@@ -9,7 +9,6 @@ uses the paper-shaped configuration (slow on CPU).
 from __future__ import annotations
 
 import json
-import os
 import pathlib
 import time
 
@@ -94,15 +93,16 @@ def write_bench(name: str, payload: dict) -> pathlib.Path:
 
 
 def engine_cache(max_entries: int | None = None):
-    """Build the benchmark-suite :class:`repro.core.cache.EngineCache`,
-    honoring ``REPRO_XLA_CACHE_DIR``: when that env var names a directory,
-    compiled XLA executables persist there across benchmark PROCESSES
-    (``EngineCache(persist_dir=...)``), so a re-run of ``-m benchmarks.run``
-    or a CI shard starts warm. Unset => a plain in-process cache."""
-    from repro.core.cache import EngineCache
+    """Build the benchmark-suite :class:`repro.core.cache.EngineCache` over
+    the resolved persistent compile cache
+    (:func:`repro.core.cache.use_compile_cache`: ``JAX_COMPILATION_CACHE_DIR``
+    when set, else ``<checkout>/.jax_cache``), so compiled XLA executables
+    survive across benchmark PROCESSES and a re-run of
+    ``-m benchmarks.run`` or a CI shard starts warm."""
+    from repro.core.cache import EngineCache, use_compile_cache
 
-    return EngineCache(persist_dir=os.environ.get("REPRO_XLA_CACHE_DIR")
-                       or None, max_entries=max_entries)
+    use_compile_cache()
+    return EngineCache(max_entries=max_entries)
 
 
 def fmt_to_target(v, fmt: str = "{:.1f} s"):

@@ -3,6 +3,11 @@
 
 One module per paper table/figure (DESIGN.md §9). ``--quick`` (default)
 scales node counts / rounds to CPU; ``--full`` uses paper-shaped configs.
+
+Every suite runs in this one process, except that ``warm_start`` starts
+child processes which each need the accelerator. A chip belongs to one
+process at a time, so those suites run FIRST, before any in-process suite
+has initialised a JAX backend here (``SPAWNS_ACCELERATOR_CHILDREN``).
 """
 from __future__ import annotations
 
@@ -42,6 +47,8 @@ SUITES = {
     #   LAST: diffs the records this very invocation just appended
 }
 
+SPAWNS_ACCELERATOR_CHILDREN = ("warm_start",)
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -52,6 +59,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     names = args.only or list(SUITES)
+    # stable: child-spawning suites first, the rest in their given order
+    names = sorted(names, key=lambda n: n not in SPAWNS_ACCELERATOR_CHILDREN)
     failures = []
     for name in names:
         print(f"\n{'='*72}\n== {name}\n{'='*72}", flush=True)

@@ -7,7 +7,7 @@ benchmark proves the headline claim: a 1024-node FACADE run on an
 8-device mesh sustains near-linear *per-device-time* throughput versus
 a single-device run at the matched per-device node count (128).
 
-Methodology (single-core CPU with forced host devices): the 8 "devices"
+Methodology (CPU with forced host devices): the 8 "devices"
 from ``--xla_force_host_platform_device_count=8`` timeshare one physical
 core, so wall time approximates *aggregate device busy time*. Throughput
 is therefore measured in node-rounds per wall-second (== node-rounds per
@@ -72,15 +72,18 @@ def _child_payload(spec: dict) -> dict:
 def _spawn(n_devices: int, spec: dict) -> dict:
     """Run ``_child_payload`` in a fresh interpreter with ``n_devices``
     forced host devices — the flag must be set BEFORE jax is imported,
-    which only a new process guarantees."""
+    which only a new process guarantees. The children are a CPU study by
+    construction and say so: ``JAX_PLATFORMS=cpu`` keeps them off an
+    accelerator the parent may hold (where the flag would mean nothing
+    and the "mesh" would silently shrink to one chip)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_devices} "
         + env.get("XLA_FLAGS", "")).strip()
     src = str(pathlib.Path(__file__).resolve().parent.parent / "src")
     env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                if env.get("PYTHONPATH") else "")
-    env.pop("REPRO_XLA_CACHE_DIR", None)  # time real compiles per child
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.scale_curve", "--child",
          json.dumps(spec)],

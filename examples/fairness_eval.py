@@ -16,12 +16,14 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent / "src"))
 import numpy as np
 
 from repro.configs.facade_paper import lenet
+from repro.core.cache import use_compile_cache
 from repro.core.runner import run_experiment
 from repro.data.synthetic import SynthSpec, make_clustered_data
 from repro.fairness.metrics import fair_accuracy
 
 
 def main():
+    use_compile_cache()   # compiles persist across runs (repro.core.cache)
     spec = SynthSpec(n_classes=4, image_size=16, samples_per_class=16,
                      test_per_class=32, seed=3)
     ds = make_clustered_data(spec, (7, 1), ("rot0", "rot180"))

@@ -16,6 +16,7 @@ import tempfile
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent / "src"))
 
 from repro.configs.facade_paper import lenet
+from repro.core.cache import use_compile_cache
 from repro.core.runner import run_experiment
 from repro.data.synthetic import SynthSpec, make_clustered_data
 from repro.obs import Obs, ObsConfig
@@ -23,6 +24,7 @@ from repro.obs.report import build_report
 
 
 def main():
+    use_compile_cache()   # compiles persist across runs (repro.core.cache)
     # --- a small imbalanced clustered dataset (quickstart's setup) --------
     spec = SynthSpec(n_classes=4, image_size=16, samples_per_class=16,
                      test_per_class=32, seed=3)

@@ -18,7 +18,6 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import api, cnn, layers, transformer, whisper
@@ -44,10 +43,10 @@ def node_matmul(a, x):
         xg = jax.lax.all_gather(x_blk, meshctx.NODE_AXIS, tiled=True)
         return jnp.einsum("ij,j...->i...", a_blk, xg)
 
-    return shard_map(blk, mesh=mesh,
-                     in_specs=(P(meshctx.NODE_AXIS, None),
-                               P(meshctx.NODE_AXIS)),
-                     out_specs=P(meshctx.NODE_AXIS))(a, x)
+    return jax.shard_map(blk, mesh=mesh,
+                         in_specs=(P(meshctx.NODE_AXIS, None),
+                                   P(meshctx.NODE_AXIS)),
+                         out_specs=P(meshctx.NODE_AXIS))(a, x)
 
 
 def node_head_matmul(a, onehot, h):
@@ -64,11 +63,11 @@ def node_head_matmul(a, onehot, h):
         hg = jax.lax.all_gather(h_blk, meshctx.NODE_AXIS, tiled=True)
         return jnp.einsum("ij,jc,j...->ic...", a_blk, og, hg)
 
-    return shard_map(blk, mesh=mesh,
-                     in_specs=(P(meshctx.NODE_AXIS, None),
-                               P(meshctx.NODE_AXIS),
-                               P(meshctx.NODE_AXIS)),
-                     out_specs=P(meshctx.NODE_AXIS))(a, onehot, h)
+    return jax.shard_map(blk, mesh=mesh,
+                         in_specs=(P(meshctx.NODE_AXIS, None),
+                                   P(meshctx.NODE_AXIS),
+                                   P(meshctx.NODE_AXIS)),
+                         out_specs=P(meshctx.NODE_AXIS))(a, onehot, h)
 
 
 def node_vmap(fn):
@@ -96,8 +95,8 @@ def node_vmap(fn):
         out_specs = jax.tree.map(
             lambda s: P(meshctx.NODE_AXIS,
                         *([None] * (len(s.shape) - 1))), out_sds)
-        return shard_map(jax.vmap(fn), mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(*args)
+        return jax.shard_map(jax.vmap(fn), mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
 
     return call
 

@@ -46,11 +46,17 @@ carry back into ``run_segment``.
 
 Always-warm extensions (ROADMAP Open Item 5a):
 
-* ``EngineCache(persist_dir=...)`` points JAX's persistent compilation
-  cache at a directory, so the serialized XLA executables behind every
-  entry survive the PROCESS — a second sweep (or a CI shard, or a
-  resumed grid) reaches its first dispatch without recompiling
-  (``benchmarks/warm_start.py`` measures the cross-process win). The
+* :func:`use_compile_cache` turns JAX's persistent compilation cache on
+  at ONE resolved place, so the serialized XLA executables behind every
+  entry survive the PROCESS — a second sweep, a CI shard or a resumed
+  grid reaches its first dispatch without recompiling. Where
+  ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading of it stands and
+  no code here names another directory; otherwise the cache lives at the
+  fixed ``<checkout>/.jax_cache`` (:data:`CHECKOUT_CACHE_DIR`), found from
+  this package's location. The path never varies between runs, so a
+  later process finds what an earlier one wrote.
+  ``EngineCache(persist_dir=...)`` attaches an explicit directory
+  instead (tests that need their own). The
   in-process :class:`EngineCache` keys stay the source of truth; the
   persistent layer only short-circuits XLA compilation underneath them.
 * ``EngineCache(max_entries=...)`` bounds the in-process entry count with
@@ -68,6 +74,7 @@ import contextlib
 import dataclasses
 import hashlib
 import os
+import pathlib
 import weakref
 from typing import Any
 
@@ -118,6 +125,33 @@ class EngineSpec:
     #                              checkpoint fingerprints.
 
 
+# src/repro/core/cache.py -> parents[3] is the checkout root
+CHECKOUT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def compile_cache_dir() -> str:
+    """Where the persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names when it is set, else the fixed
+    :data:`CHECKOUT_CACHE_DIR`. Never a temporary name, a process id or a
+    time: the next process must find what this one wrote."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(CHECKOUT_CACHE_DIR))
+
+
+def use_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on at
+    :func:`compile_cache_dir` and return that directory. With
+    ``JAX_COMPILATION_CACHE_DIR`` set, JAX read it at import and that
+    reading stands: only the persistence floors are lowered. Otherwise
+    the checkout's ``.jax_cache`` is attached (:func:`attach_persist_dir`)."""
+    path = compile_cache_dir()
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _lower_persist_floors()
+        _reset_jax_cache()
+        return path
+    return attach_persist_dir(path)
+
+
 def attach_persist_dir(path) -> str:
     """Point JAX's persistent compilation cache at ``path`` (created if
     missing) and drop the persistence floors so the sweeps' many small
@@ -135,13 +169,16 @@ def attach_persist_dir(path) -> str:
     path = str(path)
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:   # knob absent on old jax: size floor stays default
-        pass
+    _lower_persist_floors()
     _reset_jax_cache()
     return path
+
+
+def _lower_persist_floors() -> None:
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def detach_persist_dir() -> None:
@@ -160,11 +197,9 @@ def _reset_jax_cache() -> None:
     next compile re-reads ``jax_compilation_cache_dir``. Without this,
     attaching after the process's first compile is silently a no-op (the
     singleton latched the old — usually absent — directory)."""
-    try:
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:   # private module moved: newer jax re-reads config
-        pass
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
 
 
 _FP_MEMO: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
@@ -245,9 +280,11 @@ class EngineCache:
     builds, monotone across LRU evictions — which is what sweep smokes
     assert stays flat after each cell's first run.
 
-    ``persist_dir``: attach JAX's persistent compilation cache (see
-    :func:`attach_persist_dir`) so compiled executables survive the
-    process. ``max_entries``: LRU bound on live entries; ``None`` (the
+    ``persist_dir``: attach JAX's persistent compilation cache at this
+    explicit directory (see :func:`attach_persist_dir`) so compiled
+    executables survive the process — for tests that need a directory of
+    their own; everything else uses :func:`use_compile_cache`.
+    ``max_entries``: LRU bound on live entries; ``None`` (the
     default) keeps the historical unbounded behavior.
 
     The attached directory is PROCESS-GLOBAL jax state, so a cache built

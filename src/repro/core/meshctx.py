@@ -70,11 +70,15 @@ def build(shape) -> "Mesh | None":
     (size,) = normalize(shape)
     devices = jax.devices()
     if size > len(devices):
+        platform = devices[0].platform
+        hint = ""
+        if platform == "cpu":
+            hint = (f"; set XLA_FLAGS=--xla_force_host_platform_device_"
+                    f"count={size} BEFORE importing jax (the "
+                    "launch/dryrun.py subprocess pattern)")
         raise RuntimeError(
-            f"node mesh ({size},) needs {size} devices, have "
-            f"{len(devices)}; set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={size} BEFORE importing jax (the "
-            "launch/dryrun.py subprocess pattern)")
+            f"node mesh ({size},) needs {size} devices, but {platform} "
+            f"shows {len(devices)}{hint}")
     return Mesh(np.asarray(devices[:size]), (NODE_AXIS,))
 
 

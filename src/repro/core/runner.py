@@ -195,7 +195,10 @@ def make_evaluator(binding: Binding, node_cluster, test_x, test_y,
     prediction asynchronously (no host sync), ``finish`` drains and
     reduces. The pipelined engine driver uses the split to overlap eval
     compute/drain with the next segment's device compute;
-    ``evaluate(models)`` == ``finish(begin(models))``.
+    ``evaluate(models)`` == ``finish(begin(models))``. ``begin`` is
+    ``evaluate.predict`` over ``evaluate.inputs(models)``, the per-cluster
+    (gathered models, eval batches) pairs — exposed so their device
+    placement and the program's memory can be inspected.
     """
     cfg = binding.cfg
     node_cluster = np.asarray(node_cluster)
@@ -220,9 +223,12 @@ def make_evaluator(binding: Binding, node_cluster, test_x, test_y,
 
         return jax.lax.map(per_batch, xb)            # [nb, m, B]
 
-    def begin(models):
-        return [predict(jax.tree.map(lambda l: l[idx], models), xb)
+    def inputs(models):
+        return [(jax.tree.map(lambda l: l[idx], models), xb)
                 for idx, xb, _, _ in clusters]
+
+    def begin(models):
+        return [predict(models_c, xb) for models_c, xb in inputs(models)]
 
     def finish(pending):
         accs, preds_c, labels_c = [], [], []
@@ -242,6 +248,8 @@ def make_evaluator(binding: Binding, node_cluster, test_x, test_y,
 
     evaluate.begin = begin
     evaluate.finish = finish
+    evaluate.inputs = inputs        # per cluster: (gathered models, batches)
+    evaluate.predict = predict      # the jitted per-cluster program
     evaluate.cluster_ids = tuple(int(node_cluster[idx[0]])
                                  for idx, _, _, _ in clusters)
     return evaluate

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU):
+"""Pallas TPU kernels (checked against their oracles in interpret mode, and
+compiled for a described v5e chip at real widths by the tests):
 
   * flash_attention — blocked causal GQA attention (train/prefill hot spot)
   * head_select     — FACADE step-2c fused k-head cross-entropy

@@ -1,3 +1,3 @@
 from .kernel import flash_attention  # noqa: F401
-from .ops import attention_auto, flash_attention_op  # noqa: F401
+from .ops import flash_attention_op  # noqa: F401
 from .ref import attention_ref  # noqa: F401

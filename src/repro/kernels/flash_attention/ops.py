@@ -1,9 +1,5 @@
-"""Jit'd public wrapper for the flash-attention kernel.
-
-On this CPU container the kernel executes in interpret mode (the TPU
-lowering is the target); ``attention_auto`` picks the kernel on TPU and the
-oracle elsewhere, so the model code can call one function everywhere.
-"""
+"""Jit'd public wrapper for the flash-attention kernel (pass
+``interpret=True`` to run it off the TPU)."""
 from __future__ import annotations
 
 import functools
@@ -11,7 +7,7 @@ import functools
 import jax
 
 from .kernel import flash_attention
-from .ref import attention_ref
+from .ref import attention_ref  # noqa: F401  (the oracle, next to the op)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -23,8 +19,3 @@ def flash_attention_op(q, k, v, *, causal: bool = True, window: int = 0,
                            block_q=block_q, block_kv=block_kv,
                            interpret=interpret)
 
-
-def attention_auto(q, k, v, *, causal: bool = True, window: int = 0):
-    if jax.default_backend() == "tpu":
-        return flash_attention_op(q, k, v, causal=causal, window=window)
-    return attention_ref(q, k, v, causal=causal, window=window)

@@ -37,10 +37,10 @@ def _kernel(f_ref, w_ref, lab_ref, out_ref, m_ref, l_ref, g_ref, *,
     logits = jax.lax.dot_general(f, w, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
 
-    labs = lab_ref[...][:, 0]                            # [bt]
+    labs = lab_ref[...]                                  # [bt, 1]
     cols = vi * block_v + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1)
-    gold_hit = labs[:, None] == cols
+    gold_hit = labs == cols
     g_ref[...] += jnp.where(gold_hit, logits, 0.0).sum(
         axis=1, keepdims=True)
 
@@ -54,9 +54,8 @@ def _kernel(f_ref, w_ref, lab_ref, out_ref, m_ref, l_ref, g_ref, *,
     @pl.when(vi == n_v - 1)
     def _done():
         lse = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
-        valid = (labs >= 0)[:, None]
-        nll = jnp.where(valid, lse - g_ref[...], 0.0)
-        out_ref[0, 0] = nll.sum()
+        nll = jnp.where(labs >= 0, lse - g_ref[...], 0.0)
+        out_ref[...] = jnp.full(out_ref.shape, nll.sum(), out_ref.dtype)
 
 
 def head_select_losses(features, heads, labels, *, block_t: int = 128,
@@ -79,8 +78,11 @@ def head_select_losses(features, heads, labels, *, block_t: int = 128,
             pl.BlockSpec((1, d, block_v), lambda ki, ti, vi: (ki, 0, vi)),
             pl.BlockSpec((block_t, 1), lambda ki, ti, vi: (ti, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda ki, ti, vi: (ki, ti)),
-        out_shape=jax.ShapeDtypeStruct((k, n_t), jnp.float32),
+        # one lane-dense (1, 128) row per (head, token-block), the partial
+        # sum broadcast along it: a (1, 1) block breaks the (8, 128) tiling
+        out_specs=pl.BlockSpec((1, 1, 1, 128),
+                               lambda ki, ti, vi: (ki, ti, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, n_t, 1, 128), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.float32),
@@ -90,4 +92,4 @@ def head_select_losses(features, heads, labels, *, block_t: int = 128,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(features, heads, labels[:, None].astype(jnp.int32))
-    return partial.sum(axis=1)
+    return partial[:, :, 0, 0].sum(axis=1)

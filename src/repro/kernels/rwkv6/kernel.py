@@ -29,18 +29,21 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref, s_ref, *,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    u = u_ref[0]                                         # [hd]
+    u = u_ref[0]                                         # [1, hd]
 
+    # every per-step operand is a [1, hd] row: Mosaic has no matmul with a
+    # 1-D operand, and the row's transpose gives the column the outer
+    # products need
     def step(t, _):
-        r = r_ref[0, t]                                  # [hd]
-        k = k_ref[0, t]
-        v = v_ref[0, t]
-        w = w_ref[0, t]
+        r = r_ref[0, pl.ds(t, 1), :]                     # [1, hd]
+        k = k_ref[0, pl.ds(t, 1), :]
+        v = v_ref[0, pl.ds(t, 1), :]
+        w = w_ref[0, pl.ds(t, 1), :]
         s = s_ref[...]                                   # [hd, hd]
         bonus = jnp.sum(r * u * k)                       # scalar
-        y = r @ s + bonus * v                            # [hd]
-        y_ref[0, t] = y.astype(y_ref.dtype)
-        s_ref[...] = w[:, None] * s + k[:, None] * v[None, :]
+        y = jnp.dot(r, s, preferred_element_type=jnp.float32) + bonus * v
+        y_ref[0, pl.ds(t, 1), :] = y.astype(y_ref.dtype)
+        s_ref[...] = w.T * s + k.T * v
         return 0
 
     jax.lax.fori_loop(0, block_t, step, 0)
@@ -61,7 +64,9 @@ def wkv_kernel(r, k, v, w, u, *, block_t: int = 64, interpret: bool = False):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
 
     rf, kf, vf, wf = map(flat, (r, k, v, w))
-    uf = jnp.broadcast_to(u[None], (b, h, hd)).reshape(b * h, hd)
+    # u rides as [B*H, 1, hd]: a (1, hd) block of a 2-D array breaks the
+    # (8, 128) tiling, a (1, 1, hd) block spans the last two dims whole
+    uf = jnp.broadcast_to(u[None], (b, h, hd)).reshape(b * h, 1, hd)
 
     kernel = functools.partial(_kernel, block_t=block_t, n_t=n_t)
     y, s_f = pl.pallas_call(
@@ -72,7 +77,7 @@ def wkv_kernel(r, k, v, w, u, *, block_t: int = 64, interpret: bool = False):
             pl.BlockSpec((1, block_t, hd), lambda bh, ti: (bh, ti, 0)),
             pl.BlockSpec((1, block_t, hd), lambda bh, ti: (bh, ti, 0)),
             pl.BlockSpec((1, block_t, hd), lambda bh, ti: (bh, ti, 0)),
-            pl.BlockSpec((1, hd), lambda bh, ti: (bh, 0)),
+            pl.BlockSpec((1, 1, hd), lambda bh, ti: (bh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_t, hd), lambda bh, ti: (bh, ti, 0)),

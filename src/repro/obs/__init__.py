@@ -119,7 +119,7 @@ class Obs:
 
     def profile(self):
         """Context manager: ``jax.profiler`` trace when ``profile_dir``
-        is set and the profiler works here, else a no-op."""
+        is set (a failing profiler raises), else a no-op."""
         return maybe_profile(self.profile_dir)
 
     # -- frames -------------------------------------------------------------

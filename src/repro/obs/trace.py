@@ -26,8 +26,8 @@ blocks: ``drain`` shrinks to the RESIDUAL wait (often ~0 once the
 pipeline is full) and the sum of ``drain`` spans no longer approximates
 device time — compare wall-clock across the ``run`` span instead. A
 ``compile`` span can also be near-instant when the executable was
-deserialized from a persistent cache dir
-(``EngineCache(persist_dir=...)``): the span still marks the first
+deserialized from the persistent compile cache
+(:func:`repro.core.cache.use_compile_cache`): the span still marks the first
 trace, but XLA loads instead of compiling.
 """
 from __future__ import annotations
@@ -96,12 +96,11 @@ class Tracer:
 
 def maybe_profile(profile_dir):
     """Optional ``jax.profiler`` trace hook: a context manager writing a
-    device trace under ``profile_dir`` when the profiler is available,
-    and a no-op otherwise (never fails a run over a missing backend)."""
+    device trace under ``profile_dir``, and a no-op when it is unset. A
+    profiler that fails raises: a run asked to trace never traces nothing
+    in silence."""
     if not profile_dir:
         return contextlib.nullcontext()
-    try:
-        import jax.profiler
-        return jax.profiler.trace(str(profile_dir))
-    except Exception:
-        return contextlib.nullcontext()
+    import jax.profiler
+
+    return jax.profiler.trace(str(profile_dir))

@@ -1,17 +1,7 @@
-"""Shared fixtures, capability gates, and the failure-set diff helper.
+"""Shared fixtures and the failure-set diff helper.
 
 NOTE: no XLA_FLAGS here — tests must see 1 CPU device (the 512-device
 mesh is exclusively the dry-run's business).
-
-Capability gates
-----------------
-Some suites exercise APIs this box's jax build may not have: the Pallas
-kernels target the post-0.4 ``pallas.tpu.CompilerParams`` surface (and
-need interpret-mode lowering to run on CPU), and the dry-run/hooks mesh
-tests need ``jax.set_mesh`` / ``jax.sharding.get_abstract_mesh``. Rather
-than fail on such boxes, the affected tests skip with an explicit reason
-via the ``requires_*`` markers below — where the capability exists they
-run exactly as before (kernels in interpret mode).
 
 Failure-set baseline tooling
 ----------------------------
@@ -41,49 +31,6 @@ ALL_ARCHS = [
     "stablelm-12b", "llava-next-34b", "whisper-tiny", "qwen3-8b",
     "llama3.2-1b", "rwkv6-1.6b",
 ]
-
-
-# ---------------------------------------------------------- capabilities --
-def _pallas_interpret_reason():
-    """None when the repo's Pallas kernels can run here (interpret mode on
-    CPU), else a skip reason. Probes both the lowering and the
-    ``pallas.tpu`` API surface the kernels are written against."""
-    try:
-        import jax.experimental.pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-    except Exception as e:  # pragma: no cover - import is fine on this box
-        return f"jax.experimental.pallas unavailable: {e!r}"
-    if not hasattr(pltpu, "CompilerParams"):
-        return ("jax.experimental.pallas.tpu.CompilerParams missing "
-                f"(jax {jax.__version__} predates the rename; kernels "
-                "target the renamed API)")
-    try:
-        def _copy(x_ref, o_ref):
-            o_ref[...] = x_ref[...]
-
-        x = jnp.zeros((8, 128), jnp.float32)
-        pl.pallas_call(
-            _copy, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            interpret=True)(x)
-    except Exception as e:
-        return f"Pallas interpret-mode lowering unavailable here: {e!r}"
-    return None
-
-
-PALLAS_SKIP_REASON = _pallas_interpret_reason()
-
-requires_pallas = pytest.mark.skipif(
-    PALLAS_SKIP_REASON is not None,
-    reason=PALLAS_SKIP_REASON or "pallas available")
-
-requires_set_mesh = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason=f"jax.set_mesh unavailable (jax {jax.__version__})")
-
-requires_abstract_mesh = pytest.mark.skipif(
-    not hasattr(jax.sharding, "get_abstract_mesh"),
-    reason=("jax.sharding.get_abstract_mesh unavailable "
-            f"(jax {jax.__version__})"))
 
 
 # ------------------------------------------------- failure-set baseline ---

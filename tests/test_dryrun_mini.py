@@ -1,6 +1,5 @@
 """Dry-run machinery on a miniature mesh, in a subprocess (so the forced
-device count never leaks into other tests). Version-gated: skips when
-this jax build lacks ``jax.set_mesh`` (the subprocess script needs it)."""
+device count never leaks into other tests)."""
 import json
 import os
 import subprocess
@@ -8,10 +7,6 @@ import sys
 import textwrap
 
 import pytest
-
-from conftest import requires_set_mesh
-
-pytestmark = requires_set_mesh
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 

@@ -7,8 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_pallas
-
 from repro.core import facade as facade_mod
 from repro.core.bindings import make_binding
 from repro.core.state import init_facade_state
@@ -37,7 +35,6 @@ def test_facade_round_on_lm(arch):
         assert np.all(np.isfinite(np.asarray(leaf, np.float32)))
 
 
-@requires_pallas
 def test_head_select_kernel_agrees_with_binding():
     """The Pallas fused-CE kernel and the binding's head_loss must rank the
     k candidate heads identically (same argmin -> same clustering)."""

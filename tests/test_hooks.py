@@ -1,12 +1,7 @@
-"""Sharding hooks: no-op without a mesh, divisibility guards, fallbacks.
-
-The mesh-aware cases are version-gated on the jax APIs they exercise
-(``jax.sharding.get_abstract_mesh`` / ``jax.set_mesh``)."""
+"""Sharding hooks: no-op without a mesh, divisibility guards, fallbacks."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from conftest import requires_abstract_mesh, requires_set_mesh
 
 from repro.models import hooks
 
@@ -15,7 +10,6 @@ def teardown_function(_fn):
     hooks.clear()
 
 
-@requires_abstract_mesh
 def test_noop_without_mesh():
     hooks.set_activation_sharding(("data",), "model")
     x = jnp.ones((4, 8))
@@ -34,7 +28,6 @@ def test_noop_when_cleared():
     assert hooks.data_axis_size() == 1
 
 
-@requires_set_mesh
 def test_constraints_inside_mesh(tmp_path):
     """In a subprocess with 8 forced devices, hooks insert constraints with
     correct divisibility behavior."""

@@ -1,26 +1,18 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode).
 
 Assignment: "For each Pallas kernel, sweep shapes/dtypes and
-assert_allclose against the ref.py pure-jnp oracle."
-
-Capability-gated: the whole module skips (with the probe's reason) when
-Pallas interpret-mode lowering — or the ``pallas.tpu`` API surface the
-kernels are written against — is unavailable on this box; where it works
-the sweeps run in interpret mode as before.
+assert_allclose against the ref.py pure-jnp oracle." That the kernels
+compile for the chip is ``tests/test_tpu_compile.py``'s business.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_pallas
-
 from repro.kernels.flash_attention import ops as fa
 from repro.kernels.head_select import ops as hs
 from repro.kernels.head_select.ref import head_losses_ref
 from repro.kernels.rwkv6 import ops as rw
-
-pytestmark = requires_pallas
 
 
 # --------------------------------------------------------------------------
