@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import api, cnn, layers, transformer, whisper
 from repro.models.base import CNNConfig, ModelConfig
+from repro.obs.trace import scope
 
 from . import meshctx
 
@@ -110,6 +111,7 @@ class Binding(NamedTuple):
     head_loss: Callable     # (head, feats, batch) -> scalar
 
 
+@scope("local_sgd")
 def local_sgd(binding: "Binding", params, batches_h, lr):
     """H plain-SGD steps (paper step 2d) on one node's params.
 
@@ -126,6 +128,7 @@ def local_sgd(binding: "Binding", params, batches_h, lr):
     return params
 
 
+@scope("gossip")
 def gossip_mix(w, tree, visible=None, guard=None):
     """Row-stochastic gossip mixing (Eq. 3): ``out_i = sum_j W_ij x_j``
     over node-stacked pytrees — THE one mixing definition shared by FACADE

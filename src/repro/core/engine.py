@@ -29,7 +29,6 @@ granularity — exactly the rounds where the legacy driver evaluated.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Callable, NamedTuple
 
 import jax
@@ -40,17 +39,11 @@ from repro import resil as resil_mod
 from repro import topo as topo_mod
 from repro.data import pipeline
 from repro.obs import frame as obs_frame
+from repro.obs.trace import span
 
 from . import meshctx
 from .netwire import round_seconds
 from .state import EngineCarry
-
-
-def _sp(tracer, name, **attrs):
-    """Tracer span or no-op — the engine never requires an ``Obs``."""
-    if tracer is None:
-        return contextlib.nullcontext()
-    return tracer.span(name, **attrs)
 
 
 class Segment(NamedTuple):
@@ -221,23 +214,26 @@ class SegmentEngine:
                                                   h, b), n)
                 conds = published = None
                 if net is not None:
-                    conds, chan = netsim.advance_conditions(net, n, rnd,
-                                                            chan)
-                    conds, fault, restarted = resil_mod.advance(
-                        net, n, rnd, conds, fault)
-                    if restarted is not None:
-                        prev_state = resil_mod.reset_nodes(
-                            n, restarted, fault.init, prev_state)
-                    conds, published = netsim.apply_async(net, conds, gossip)
+                    with jax.named_scope("netsim"):
+                        conds, chan = netsim.advance_conditions(net, n, rnd,
+                                                                chan)
+                        conds, fault, restarted = resil_mod.advance(
+                            net, n, rnd, conds, fault)
+                        if restarted is not None:
+                            prev_state = resil_mod.reset_nodes(
+                                n, restarted, fault.init, prev_state)
+                        conds, published = netsim.apply_async(net, conds,
+                                                              gossip)
                 state, info = round_fn(prev_state, batches, net=conds,
                                        gossip=published, topo=topo)
-                if published is not None:
-                    gossip = netsim.fold_gossip(net, gossip, conds,
-                                                mixable_of(state))
-                # fold this round's observed conditions into the policy
-                # EWMAs AFTER the round: round t samples from what was
-                # seen up to t-1 (no-op when topo is off / net is None)
-                topo = topo_mod.advance(tcfg, net, topo, conds)
+                with jax.named_scope("netsim"):
+                    if published is not None:
+                        gossip = netsim.fold_gossip(net, gossip, conds,
+                                                    mixable_of(state))
+                    # fold this round's observed conditions into the policy
+                    # EWMAs AFTER the round: round t samples from what was
+                    # seen up to t-1 (no-op when topo is off / net is None)
+                    topo = topo_mod.advance(tcfg, net, topo, conds)
                 out = {"round_bytes": info["round_bytes"],
                        "round_s": round_seconds(net, info, conds, h)}
                 if track:
@@ -269,9 +265,10 @@ class SegmentEngine:
         bookkeeping overlaps device compute. The input ``carry`` is
         donated — consumed either way.
 
-        ``tracer`` wraps the call in a ``compile`` span (first trace of
-        this program in this process) or a ``dispatch`` span (async:
-        trace + enqueue only).
+        The call is a ``compile`` span (first trace of this program in
+        this process) or a ``dispatch`` span (async: trace + enqueue
+        only): a ``repro.*`` profiler annotation, and a ``tracer`` span
+        when a tracer is given (:func:`repro.obs.trace.span`).
         """
         key = (length, warmup)
         fn = self._compiled.get(key)
@@ -283,8 +280,8 @@ class SegmentEngine:
         if fresh:
             self._traced.add(trace_key)
             self.compile_count += 1
-        with _sp(tracer, "compile" if fresh else "dispatch",
-                 length=length, warmup=warmup):
+        with span(tracer, "compile" if fresh else "dispatch",
+                  length=length, warmup=warmup):
             return fn(carry, jnp.asarray(start, jnp.int32),
                       train_x, train_y)
 
@@ -294,8 +291,8 @@ class SegmentEngine:
         the ``drain`` span absorbs device compute + transfer; in the
         pipelined driver the next segment is already running, so the span
         shrinks to the residual wait."""
-        with _sp(tracer, "drain",
-                 **({} if length is None else {"length": length})):
+        with span(tracer, "drain",
+                  **({} if length is None else {"length": length})):
             return jax.device_get(outs)
 
     def run_segment(self, carry: EngineCarry, start: int, length: int,

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro import resil
 from repro import topo as topo_mod
+from repro.obs.trace import scope
 
 from . import split, topology
 from .bindings import (Binding, gossip_mix, local_sgd, node_head_matmul,
@@ -43,6 +44,7 @@ class FacadeConfig:
 
 
 # --------------------------------------------------------------------------
+@scope("gossip")
 def _aggregate_heads(adj, cluster_id, heads, k, sent_heads=None,
                      guard=None):
     """Eq. 4: for each node i and cluster j, average the heads *sent* by
@@ -104,6 +106,7 @@ def resil_tree_zero(tree, keep):
     return jax.tree.map(z, tree)
 
 
+@scope("select_heads")
 def _select_heads(binding: Binding, cores, heads, batches):
     """losses [n, k] via shared core features (paper III-E optimization)."""
     def per_node(core, heads_k, batch):

@@ -37,6 +37,7 @@ from repro.data import pipeline
 from repro.models import cnn as cnn_mod
 from repro import netsim
 from repro import obs as obs_mod
+from repro.obs.trace import span
 from repro import resil as resil_mod
 from repro import topo as topo_mod
 
@@ -48,7 +49,7 @@ from .baselines import (DACConfig, DeprlConfig, DpsgdConfig, ELConfig,
                         init_dac_extra)
 from .bindings import Binding
 from .cache import EngineCache, EngineSpec
-from .engine import _sp, segment_plan
+from .engine import segment_plan
 from .state import EngineCarry, init_baseline_state, init_facade_state
 
 
@@ -211,8 +212,14 @@ def make_evaluator(binding: Binding, node_cluster, test_x, test_y,
         # cap the batch at the test-set size: padding waste stays < one row
         xb, mask = pipeline.padded_eval_batches(
             x, min(batch, max(1, x.shape[0])))
-        clusters.append((idx, jnp.asarray(xb),
-                         mask.reshape(-1) > 0, np.asarray(test_y[c])))
+        clusters.append((idx, xb, mask.reshape(-1) > 0,
+                         np.asarray(test_y[c])))
+    # the padded test batches go to the device once, when the evaluator
+    # is built (no tracer here: the cache builds evaluators)
+    with span(None, "upload", bytes=sum(xb.nbytes for _, xb, _, _ in
+                                        clusters)):
+        clusters = [(idx, jnp.asarray(xb), valid, y)
+                    for idx, xb, valid, y in clusters]
 
     @jax.jit
     def predict(models_c, xb):                       # xb [nb, B, ...]
@@ -221,7 +228,8 @@ def make_evaluator(binding: Binding, node_cluster, test_x, test_y,
                 lambda p: cnn_mod.forward(cfg, p, x))(models_c)
             return jnp.argmax(logits, -1)            # [m, B]
 
-        return jax.lax.map(per_batch, xb)            # [nb, m, B]
+        with jax.named_scope("predict"):
+            return jax.lax.map(per_batch, xb)        # [nb, m, B]
 
     def inputs(models):
         return [(jax.tree.map(lambda l: l[idx], models), xb)
@@ -474,9 +482,6 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
     key = jax.random.PRNGKey(seed)
     k_init, k_data = jax.random.split(key)
 
-    train_x = jnp.asarray(dataset.train_x)
-    train_y = jnp.asarray(dataset.train_y)
-
     cache = cache if cache is not None else EngineCache()
     tracer = obs.tracer if obs is not None else None
     spec = EngineSpec(
@@ -488,38 +493,44 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
     if obs is not None:
         obs.begin_run(algo=algo, seed=seed, rounds=rounds, engine=engine)
     misses0 = cache.misses
-    with _sp(tracer, "cache.entry", algo=algo):
+    with span(tracer, "cache.entry", algo=algo):
         entry = cache.entry(spec, tracer=tracer)
     if tracer is not None:
         tracer.event("cache.miss" if cache.misses > misses0
                      else "cache.hit", algo=algo, seed=seed)
-    builds0 = cache.evaluator_builds
-    # commit the node-stacked train arrays to the entry's node mesh (a
-    # no-op when mesh=None) so every segment reads its shard locally
-    train_x, train_y = entry.engine.place_data(train_x, train_y)
-    setup = entry.setup(k_init)
-    evaluator = cache.evaluator(entry.binding, dataset,
-                                batch=spec.eval_batch)
-    if tracer is not None and cache.evaluator_builds > builds0:
-        tracer.event("evaluator.build", batch=spec.eval_batch)
-    hist = _History(dataset.node_cluster, n, evaluator, setup.models_of,
-                    target_acc, verbose, algo, entry.binding.cfg.n_classes,
-                    tiers=(np.asarray(obs_mod.tiers_of(net, n))
-                           if net is not None else None),
-                    obs=obs)
-    ckpt_fp = None
-    if ckpt is not None:
-        # everything that shapes the trajectory or the resume schedule;
-        # a stale checkpoint from any other configuration is refused
-        ckpt_fp = obs_mod.fingerprint({
-            "spec": repr(spec), "seed": seed, "rounds": rounds,
-            "eval_every": eval_every, "warmup_rounds": warmup_rounds,
-            "target": repr(target_acc)})
     prof = obs.profile() if obs is not None else contextlib.nullcontext()
     # pin the entry while the run is live: an LRU-bounded cache must never
     # evict the engine whose donated carry/segment programs are in flight
     with prof, cache.pin(spec), \
-            _sp(tracer, "run", algo=algo, seed=seed, engine=engine):
+            span(tracer, "run", algo=algo, seed=seed, engine=engine):
+        # commit the node-stacked train arrays to the device, on the
+        # entry's node mesh when there is one, so every segment reads its
+        # shard locally
+        with span(tracer, "upload",
+                  bytes=host_bytes(dataset.train_x, dataset.train_y)):
+            train_x, train_y = entry.engine.place_data(
+                jnp.asarray(dataset.train_x), jnp.asarray(dataset.train_y))
+        with span(tracer, "setup"):
+            setup = entry.setup(k_init)
+        builds0 = cache.evaluator_builds
+        evaluator = cache.evaluator(entry.binding, dataset,
+                                    batch=spec.eval_batch)
+        if tracer is not None and cache.evaluator_builds > builds0:
+            tracer.event("evaluator.build", batch=spec.eval_batch)
+        hist = _History(dataset.node_cluster, n, evaluator, setup.models_of,
+                        target_acc, verbose, algo,
+                        entry.binding.cfg.n_classes,
+                        tiers=(np.asarray(obs_mod.tiers_of(net, n))
+                               if net is not None else None),
+                        obs=obs)
+        ckpt_fp = None
+        if ckpt is not None:
+            # everything that shapes the trajectory or the resume schedule;
+            # a stale checkpoint from any other configuration is refused
+            ckpt_fp = obs_mod.fingerprint({
+                "spec": repr(spec), "seed": seed, "rounds": rounds,
+                "eval_every": eval_every, "warmup_rounds": warmup_rounds,
+                "target": repr(target_acc)})
         if engine:
             _drive_engine(entry.engine, setup, hist, k_data, train_x,
                           train_y, rounds=rounds, eval_every=eval_every,
@@ -554,6 +565,13 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
             timing=obs.tracer.rollup(), cache=cache.stats(),
             health=health))
     return hist.result(algo)
+
+
+def host_bytes(*arrays) -> int:
+    """Bytes a host-to-device copy of ``arrays`` moves: each host array's
+    ``nbytes``; an array already on the device counts 0."""
+    return sum(0 if isinstance(a, jax.Array) else np.asarray(a).nbytes
+               for a in arrays)
 
 
 # --------------------------------------------------------------------------
@@ -739,7 +757,8 @@ def _drive_engine(eng, setup: AlgoSetup, hist: _History, k_data,
     """
     tracer = obs.tracer if obs is not None else None
     plan = segment_plan(rounds, eval_every, warmup_rounds)
-    carry = eng.init_carry(setup.state, k_data)
+    with span(tracer, "setup"):
+        carry = eng.init_carry(setup.state, k_data)
     start_idx = 0
     n_frames = 0        # frame sidecar files already on disk
     if ckpt is not None and os.path.exists(ckpt):
@@ -763,36 +782,49 @@ def _drive_engine(eng, setup: AlgoSetup, hist: _History, k_data,
         rnds = np.arange(seg.start + 1, seg.start + seg.length + 1)
         if obs is not None and "frame" in outs:
             obs.record_frames(rnds, outs["frame"])
+        _record_comm(hist, rnds, outs, seg.eval_at_end, tracer)
         hit = False
         if seg.eval_at_end:
-            hist.comm.record_bulk(rnds[:-1], outs["round_bytes"][:-1],
-                                  outs["round_s"][:-1])
             state = carry.state
             if seg.start + seg.length == rounds:
-                state = setup.finalize(state)
+                with span(tracer, "finalize"):
+                    state = setup.finalize(state)
                 carry = carry._replace(state=state)
-            with _sp(tracer, "eval", round=int(rnds[-1])):
+            with span(tracer, "eval", round=int(rnds[-1])):
                 hit = hist.eval_round(state, int(rnds[-1]),
                                       float(outs["round_bytes"][-1]),
                                       float(outs["round_s"][-1]))
-        else:
-            hist.comm.record_bulk(rnds, outs["round_bytes"],
-                                  outs["round_s"])
-        if setup.track_cluster:
-            # legacy parity: on a target_acc hit the loop broke BEFORE
-            # appending the eval round's cluster ids
-            upto = len(rnds) - 1 if hit else len(rnds)
-            for i in range(upto):
-                hist.cluster_hist.append(
-                    (int(rnds[i]), np.asarray(outs["cluster_id"][i])))
+        _record_clusters(hist, setup, rnds, outs, hit, tracer)
         if ckpt is not None:
             new_fr = (rnds, outs["frame"]) if "frame" in outs else None
             finished = hit or idx + 1 == len(plan)
-            with _sp(tracer, "ckpt.save", segment=idx, finished=finished):
+            with span(tracer, "ckpt.save", segment=idx, finished=finished):
                 n_frames = _ckpt_save(ckpt, ckpt_fp, carry, hist, new_fr,
                                       n_frames, idx + 1, finished)
         if hit:
             break
+
+
+def _record_comm(hist: _History, rnds, outs, eval_at_end: bool, tracer):
+    """Log a drained segment's per-round bytes and seconds, all but an
+    eval round's (the eval records that one)."""
+    m = len(rnds) - 1 if eval_at_end else len(rnds)
+    with span(tracer, "record"):
+        hist.comm.record_bulk(rnds[:m], outs["round_bytes"][:m],
+                              outs["round_s"][:m])
+
+
+def _record_clusters(hist: _History, setup: AlgoSetup, rnds, outs,
+                     hit: bool, tracer):
+    """Append a drained segment's per-round cluster ids (FACADE)."""
+    if not setup.track_cluster:
+        return
+    # legacy parity: on a target_acc hit the loop broke BEFORE appending
+    # the eval round's cluster ids
+    with span(tracer, "record"):
+        for i in range(len(rnds) - 1 if hit else len(rnds)):
+            hist.cluster_hist.append(
+                (int(rnds[i]), np.asarray(outs["cluster_id"][i])))
 
 
 def _drive_pipelined(eng, setup: AlgoSetup, hist: _History, carry, plan,
@@ -833,9 +865,11 @@ def _drive_pipelined(eng, setup: AlgoSetup, hist: _History, carry, plan,
         if seg.eval_at_end:
             state = carry.state
             if seg.start + seg.length == rounds:
-                state = setup.finalize(state)
+                with span(tracer, "finalize"):
+                    state = setup.finalize(state)
                 carry = carry._replace(state=state)
-            ev = hist.eval_begin(state)
+            with span(tracer, "eval", round=seg.start + seg.length):
+                ev = hist.eval_begin(state)
         snap = None
         if idx + 1 < len(plan):
             if ckpt is not None:
@@ -849,26 +883,18 @@ def _drive_pipelined(eng, setup: AlgoSetup, hist: _History, carry, plan,
         rnds = np.arange(seg.start + 1, seg.start + seg.length + 1)
         if obs is not None and "frame" in outs:
             obs.record_frames(rnds, outs["frame"])
+        _record_comm(hist, rnds, outs, seg.eval_at_end, tracer)
         hit = False
         if seg.eval_at_end:
-            hist.comm.record_bulk(rnds[:-1], outs["round_bytes"][:-1],
-                                  outs["round_s"][:-1])
-            with _sp(tracer, "eval", round=int(rnds[-1])):
+            with span(tracer, "eval", round=int(rnds[-1])):
                 hit = hist.eval_finish(ev, int(rnds[-1]),
                                        float(outs["round_bytes"][-1]),
                                        float(outs["round_s"][-1]))
-        else:
-            hist.comm.record_bulk(rnds, outs["round_bytes"],
-                                  outs["round_s"])
-        if setup.track_cluster:
-            upto = len(rnds) - 1 if hit else len(rnds)
-            for i in range(upto):
-                hist.cluster_hist.append(
-                    (int(rnds[i]), np.asarray(outs["cluster_id"][i])))
+        _record_clusters(hist, setup, rnds, outs, hit, tracer)
         if ckpt is not None:
             new_fr = (rnds, outs["frame"]) if "frame" in outs else None
             finished = hit or idx + 1 == len(plan)
-            with _sp(tracer, "ckpt.save", segment=idx, finished=finished):
+            with span(tracer, "ckpt.save", segment=idx, finished=finished):
                 n_frames = _ckpt_save(ckpt, ckpt_fp,
                                       snap if snap is not None else carry,
                                       hist, new_fr, n_frames, idx + 1,
@@ -959,9 +985,10 @@ def _drive_legacy(setup: AlgoSetup, hist: _History, k_data, train_x, train_y,
 
         last_round = rnd == rounds - 1
         if last_round:
-            state = setup.finalize(state)
+            with span(tracer, "finalize"):
+                state = setup.finalize(state)
         if (rnd + 1) % eval_every == 0 or last_round:
-            with _sp(tracer, "eval", round=rnd + 1):
+            with span(tracer, "eval", round=rnd + 1):
                 hit = hist.eval_round(state, rnd + 1,
                                       float(info["round_bytes"]), round_s)
             if hit:

@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import scope
+
 
 def _check_degree(n: int, r: int):
     """Degrees at or above ``n`` used to silently collapse into
@@ -28,6 +30,7 @@ def _check_degree(n: int, r: int):
             f"supports 1 <= degree <= n - 1 (multi-edges collapse)")
 
 
+@scope("topology")
 def random_regular(key, n: int, r: int):
     """Random r-regular-ish undirected graph via r/2 random cycles.
 
@@ -83,6 +86,7 @@ def effective_adjacency(adj, edge_mask, active):
     return adj * edge_mask * active[:, None] * active[None, :]
 
 
+@scope("topology")
 def mixing_matrix(adj):
     """Row-stochastic W with uniform weights over {neighbors} ∪ {self}:
     W[i, j] = 1/(deg_i + 1) for j ∈ N(i) ∪ {i} (Eq. 3 aggregation).

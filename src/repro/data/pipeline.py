@@ -10,7 +10,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import scope
 
+
+@scope("sample_batches")
 def sample_round_batches(key, train_x, train_y, h: int, b: int):
     """train_x [n, N, ...], train_y [n, N] -> batches pytree [n, H, B, ...]."""
     n, per_node = train_x.shape[0], train_x.shape[1]

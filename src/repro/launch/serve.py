@@ -26,7 +26,6 @@ serving traces and training traces can be read with one
 from __future__ import annotations
 
 import argparse
-import contextlib
 import time
 
 import jax
@@ -38,6 +37,7 @@ from repro import netsim
 from repro.comm import CommLog
 from repro.models import api, transformer
 from repro.models.base import get_config, list_archs
+from repro.obs.trace import span
 
 TOKEN_BYTES = 4  # int32 token ids on the wire
 
@@ -96,11 +96,6 @@ def main(argv=None) -> None:
         from repro.obs import JsonlSink, Tracer
         tracer = Tracer(sink=JsonlSink(args.trace_jsonl))
 
-    def _sp(name, **attrs):
-        if tracer is None:
-            return contextlib.nullcontext()
-        return tracer.span(name, **attrs)
-
     cfg = get_config(args.arch, smoke=True)
     if cfg.encoder_layers > 0:
         raise SystemExit("enc-dec serving: use examples/serve_batched.py "
@@ -144,7 +139,7 @@ def main(argv=None) -> None:
         for i, r in enumerate(batch_reqs):
             toks[i, :len(r)] = r
 
-        with _sp("prefill", batch=batch_no, size=b):
+        with span(tracer, "prefill", batch=batch_no, size=b):
             logits, cache = prefill_fn(params, jnp.asarray(toks))
             # sample the first token inside the span so it absorbs the
             # prefill compute (dispatch is async; argmax forces it)
@@ -153,7 +148,8 @@ def main(argv=None) -> None:
         out_tokens = np.zeros((b, args.gen_len), np.int32)
         pos = jnp.asarray(lens)  # next position per request
         # greedy (or sampled) continuation
-        with _sp("decode", batch=batch_no, size=b, steps=args.gen_len):
+        with span(tracer, "decode", batch=batch_no, size=b,
+                  steps=args.gen_len):
             for t in range(args.gen_len):
                 out_tokens[:, t] = np.asarray(last)
                 logits, cache = decode_fn(params, cache, last[:, None], pos)

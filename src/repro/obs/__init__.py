@@ -14,9 +14,17 @@ Four layers, composable but independent:
   accuracy, cluster churn), pure host bookkeeping over arrays the
   evaluator already drains — zero extra dispatches, recorded whether
   or not a device ``ObsConfig`` is attached;
-* **host**: :class:`Tracer` (:mod:`.trace`) — nested spans around
-  compile / segment dispatch / scalar drain / eval, ``EngineCache``
-  hit/miss events, optional ``jax.profiler`` hook — plus the
+* **host**: :func:`span` + :class:`Tracer` (:mod:`.trace`) — nested
+  spans around upload / setup / compile / segment dispatch / scalar
+  drain / finalize / eval / record, ``EngineCache`` hit/miss events,
+  optional ``jax.profiler`` hook. Every span is also a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<span>``, whether or not
+  an ``Obs`` is attached, so a profile (``Obs(profile_dir=...)`` or
+  ``jax.profiler.trace``) shows the program's host work on the device's
+  clock; inside the compiled programs the algorithm's stages are
+  ``jax.named_scope`` s (``sample_batches``, ``topology``, ``gossip``,
+  ``select_heads``, ``local_sgd``, ``netsim``, ``obs_frame``,
+  ``predict``) in each operation's ``op_name`` — plus the
   :mod:`.health` rule engine judging both telemetry streams into a
   per-run :class:`HealthReport` verdict, and :mod:`.report` rendering
   manifest + JSONL into markdown/JSON run reports
@@ -65,7 +73,7 @@ from .health import (HealthConfig, HealthContext,  # noqa: F401
 from .health import evaluate as evaluate_health  # noqa: F401
 from .sink import (JsonlSink, RunManifest, bench_stamp,  # noqa: F401
                    fingerprint, read_jsonl)
-from .trace import Tracer, maybe_profile  # noqa: F401
+from .trace import Tracer, maybe_profile, span  # noqa: F401
 
 
 class Obs:

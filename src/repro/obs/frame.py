@@ -40,6 +40,8 @@ import jax.numpy as jnp
 
 from repro import netsim
 
+from .trace import scope
+
 
 @dataclasses.dataclass(frozen=True)
 class ObsConfig:
@@ -108,6 +110,7 @@ def _sq_norms(prev_tree, new_tree):
     return usq, psq
 
 
+@scope("obs_frame")
 def compute_frame(cfg: ObsConfig, n: int, tiers, prev_mix, new_mix,
                   prev_cid, new_cid, info, conds, gossip) -> MetricsFrame:
     """Build one round's :class:`MetricsFrame`. Pure observation: reads

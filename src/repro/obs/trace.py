@@ -1,4 +1,5 @@
-"""Host-side span tracer for the training and serving drivers.
+"""Program spans for the training and serving drivers, on the host and on
+the profiler's clock.
 
 Phase-level wall-clock is the instrument every later perf PR (sharding,
 pipelined segments — ROADMAP Open Items 1 and 5) needs: you cannot
@@ -10,6 +11,28 @@ each takes. :class:`Tracer` provides nested spans (``compile`` /
 :meth:`Tracer.rollup`, and an optional mirror of every record into a
 :class:`repro.obs.JsonlSink` — one JSONL format shared by training and
 serving telemetry.
+
+Every span the drivers open goes through :func:`span`, which always
+enters a ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (its
+attrs become the event's stats) and, when a :class:`Tracer` is given,
+also opens that tracer's span ``name``. With no profiler session a span
+costs a few microseconds of host time (about 5 us on a CPU host; a run
+opens a few dozen); inside one (``Obs(profile_dir=...)`` or
+``jax.profiler.trace``) the spans sit on the same clock as the device's
+operations, so an idle gap on the chip can be put down to the host work
+that was open over it. The names: ``repro.run`` (one
+experiment), ``repro.upload`` (the train arrays' host-to-device copy, stat
+``bytes``; also the evaluator's test batches when it is built),
+``repro.setup`` (initial state and carry), ``repro.compile`` /
+``repro.dispatch`` (a segment's first / later call), ``repro.drain``,
+``repro.finalize``, ``repro.eval``, ``repro.record`` (comm log and
+cluster history), ``repro.ckpt.save``, ``repro.cache.entry``; serving adds
+``repro.prefill`` / ``repro.decode``. Inside the compiled programs the
+algorithm's stages carry ``jax.named_scope`` names instead
+(``sample_batches``, ``topology``, ``gossip``, ``select_heads``,
+``local_sgd``, ``netsim``, ``obs_frame``, ``predict``, via :func:`scope`)
+in each operation's ``op_name``; a TPU trace names an operation by its
+HLO instruction, whose ``op_name`` the compiled program's text holds.
 
 Everything here is host Python around the dispatch boundary: a span
 never enters jitted code, so tracing cannot change a compiled program
@@ -33,8 +56,11 @@ trace, but XLA loads instead of compiling.
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from typing import Any
+
+import jax
 
 
 class Tracer:
@@ -81,17 +107,48 @@ class Tracer:
 
     def rollup(self) -> dict:
         """Aggregate timing per span name: ``{name: {count, total_s}}``
-        plus event counts — the ``RunManifest`` timing payload."""
+        plus event counts — the ``RunManifest`` timing payload. Spans
+        that carry a ``bytes`` attr (``upload``) also total it."""
         out: dict[str, dict] = {}
         for rec in self.spans:
             slot = out.setdefault(rec["name"],
                                   {"count": 0, "total_s": 0.0})
             slot["count"] += 1
             slot["total_s"] += rec["dur_s"]
+            if "bytes" in rec:
+                slot["bytes"] = slot.get("bytes", 0) + rec["bytes"]
         ev: dict[str, int] = {}
         for rec in self.events:
             ev[rec["name"]] = ev.get(rec["name"], 0) + 1
         return {"spans": out, "events": ev}
+
+
+@contextlib.contextmanager
+def span(tracer: "Tracer | None", name: str, **attrs: Any):
+    """One program span: a ``jax.profiler.TraceAnnotation``
+    ``repro.<name>`` with ``attrs`` as its stats, always, and the
+    ``tracer``'s span ``name`` with the same attrs when a tracer is given.
+    Yields the tracer (or ``None``)."""
+    with jax.profiler.TraceAnnotation(f"repro.{name}", **attrs):
+        if tracer is None:
+            yield None
+        else:
+            with tracer.span(name, **attrs):
+                yield tracer
+
+
+def scope(name: str):
+    """Decorator: stage the function under ``jax.named_scope(name)``, so
+    every operation it traces carries ``name`` in its ``op_name`` — how a
+    device trace tells the algorithm's stages apart inside one compiled
+    program. Metadata only: the compiled program is the same without it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 def maybe_profile(profile_dir):
@@ -101,6 +158,4 @@ def maybe_profile(profile_dir):
     in silence."""
     if not profile_dir:
         return contextlib.nullcontext()
-    import jax.profiler
-
     return jax.profiler.trace(str(profile_dir))

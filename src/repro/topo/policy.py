@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import netsim
+from repro.obs.trace import scope
 
 POLICIES = ("uniform", "reliability", "bandwidth")
 
@@ -236,6 +237,7 @@ def gumbel_graph(cfg: TopoConfig, state: TopoState, key, n: int,
     return adj * part[:, None] * part[None, :] * _offdiag(n), nbr, part
 
 
+@scope("topology")
 def sample(cfg: TopoConfig, state: TopoState, key, n: int, degree: int):
     """Draw one adaptive round graph (adjacency ``[n, n]``, float 0/1).
 
