@@ -13,7 +13,7 @@ from repro import resil
 from repro import topo as topo_mod
 
 from .. import split, topology
-from ..bindings import Binding, gossip_mix, local_sgd, node_vmap
+from ..bindings import Binding, gossip_mix, local_sgd_nodes
 from ..state import BaselineState, freeze_inactive
 from ..netwire import comm_info, masked_topology, sent_view
 
@@ -96,8 +96,7 @@ def dac_round(cfg: DACConfig, binding: Binding, state: BaselineState,
     w = topology.weighted_mixing(adj, jnp.maximum(new_sim, 1e-6))
     params = gossip_mix(w, state.params, vis, guard=guard)
 
-    params = node_vmap(lambda p, b: local_sgd(binding, p, b, cfg.lr))(
-        params, batches)
+    params = local_sgd_nodes(binding, params, batches, cfg.lr)
     if net is not None:
         params = freeze_inactive(net.active, params, state.params)
         new_sim = jnp.where(net.active[:, None] > 0, new_sim, sim)

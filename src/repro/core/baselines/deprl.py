@@ -14,7 +14,7 @@ from repro import resil
 from repro import topo as topo_mod
 
 from .. import split, topology
-from ..bindings import Binding, gossip_mix, local_sgd, node_vmap
+from ..bindings import Binding, gossip_mix, local_sgd_nodes
 from ..state import BaselineState, freeze_inactive
 from ..netwire import comm_info, masked_topology, sent_view
 
@@ -53,11 +53,8 @@ def deprl_round(cfg: DeprlConfig, binding: Binding, state: BaselineState,
     guard = resil.guard_of(fault_cfg)
     cores = gossip_mix(w, cores, vis, guard=guard)
 
-    def local(core, head, bh):
-        p = split.merge_params(core, head)
-        return local_sgd(binding, p, bh, cfg.lr)
-
-    params = node_vmap(local)(cores, heads, batches)
+    params = local_sgd_nodes(binding, split.merge_params(cores, heads),
+                             batches, cfg.lr)
     if net is not None:
         params = freeze_inactive(net.active, params, state.params)
 

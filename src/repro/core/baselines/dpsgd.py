@@ -11,7 +11,7 @@ from repro import resil
 from repro import topo as topo_mod
 
 from .. import split, topology
-from ..bindings import Binding, gossip_mix, local_sgd, node_vmap
+from ..bindings import Binding, gossip_mix, local_sgd_nodes
 from ..state import BaselineState, freeze_inactive
 from ..netwire import comm_info, masked_topology, sent_view
 
@@ -40,8 +40,7 @@ def dpsgd_round(cfg: DpsgdConfig, binding: Binding, state: BaselineState,
 
     # D-PSGD order: local train, then exchange+aggregate (stale neighbors
     # contribute their last published model instead of today's)
-    params = node_vmap(lambda p, b: local_sgd(binding, p, b, cfg.lr))(
-        state.params, batches)
+    params = local_sgd_nodes(binding, state.params, batches, cfg.lr)
     vis = sent_view(net, gossip, params, fault_cfg)
     guard = resil.guard_of(fault_cfg)
     params = gossip_mix(w, params, vis, guard=guard)

@@ -12,7 +12,7 @@ from repro import resil
 from repro import topo as topo_mod
 
 from .. import split, topology
-from ..bindings import Binding, gossip_mix, local_sgd, node_vmap
+from ..bindings import Binding, gossip_mix, local_sgd_nodes
 from ..state import BaselineState, freeze_inactive
 from ..netwire import comm_info, masked_topology, sent_view
 
@@ -46,8 +46,7 @@ def el_round(cfg: ELConfig, binding: Binding, state: BaselineState, batches,
     vis = sent_view(net, gossip, state.params, fault_cfg)
     guard = resil.guard_of(fault_cfg)
     params = gossip_mix(w, state.params, vis, guard=guard)
-    params = node_vmap(lambda p, b: local_sgd(binding, p, b, cfg.lr))(
-        params, batches)
+    params = local_sgd_nodes(binding, params, batches, cfg.lr)
     if net is not None:
         params = freeze_inactive(net.active, params, state.params)
 

@@ -71,35 +71,41 @@ def node_head_matmul(a, onehot, h):
                          out_specs=P(meshctx.NODE_AXIS))(a, onehot, h)
 
 
-def node_vmap(fn):
-    """``jax.vmap`` over the node axis, partitioned over the active node
-    mesh. Outside a mesh trace context this IS ``jax.vmap(fn)`` — same
-    jaxpr, bit for bit. Under :func:`repro.core.meshctx.activate` the
-    vmapped body runs inside ``shard_map``, so each device maps only its
-    own node block. Load-bearing for the sharded engine's scaling: XLA
-    lowers a vmapped convolution to a grouped conv whose node axis lands
-    in the FEATURE dimension, which GSPMD replicates (all-gathering every
-    activation) rather than shards — so without this wrapper the whole
-    local-training phase runs in full on every device. Per-node
-    arithmetic is untouched either way; every argument and result must be
-    node-stacked (leading dim n)."""
+def node_blocks(fn):
+    """``fn`` over node-stacked arguments and results (leading dim n),
+    partitioned over the active node mesh. Outside a mesh trace context
+    this IS ``fn``. Under :func:`repro.core.meshctx.activate` ``fn`` runs
+    inside ``shard_map``, on each device's own node block."""
     mesh = meshctx.current()
     if mesh is None:
-        return jax.vmap(fn)
+        return fn
 
     def call(*args):
         def row(l):
             return P(meshctx.NODE_AXIS, *([None] * (l.ndim - 1)))
 
         in_specs = jax.tree.map(row, args)
-        out_sds = jax.eval_shape(jax.vmap(fn), *args)
+        out_sds = jax.eval_shape(fn, *args)
         out_specs = jax.tree.map(
             lambda s: P(meshctx.NODE_AXIS,
                         *([None] * (len(s.shape) - 1))), out_sds)
-        return jax.shard_map(jax.vmap(fn), mesh=mesh, in_specs=in_specs,
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)(*args)
 
     return call
+
+
+def node_vmap(fn):
+    """``jax.vmap`` over the node axis, partitioned over the active node
+    mesh (:func:`node_blocks`). Outside a mesh trace context this IS
+    ``jax.vmap(fn)`` — same jaxpr, bit for bit. Load-bearing for the
+    sharded engine's scaling: XLA lowers a vmapped convolution to a
+    grouped conv whose node axis lands in the FEATURE dimension, which
+    GSPMD replicates (all-gathering every activation) rather than shards —
+    so without the shard_map the whole local-training phase runs in full
+    on every device. Per-node arithmetic is untouched either way; every
+    argument and result must be node-stacked (leading dim n)."""
+    return node_blocks(jax.vmap(fn))
 
 
 class Binding(NamedTuple):
@@ -109,6 +115,10 @@ class Binding(NamedTuple):
     loss: Callable          # (params, batch) -> scalar
     features: Callable      # (core, batch) -> feats
     head_loss: Callable     # (head, feats, batch) -> scalar
+    # batches [n, H, ...] -> [H, ...] packed, on which ``loss`` of
+    # node-stacked params is the sum of the nodes' losses (CNNs; None
+    # elsewhere):
+    pack: Callable | None = None
 
 
 @scope("local_sgd")
@@ -126,6 +136,55 @@ def local_sgd(binding: "Binding", params, batches_h, lr):
 
     params, _ = jax.lax.scan(step, params, batches_h)
     return params
+
+
+def sgd_path(binding: "Binding") -> str:
+    """Which local-SGD program :func:`local_sgd_nodes` builds for
+    ``binding``: ``"packed"`` or ``"vmap"``."""
+    return "vmap" if binding.pack is None else "packed"
+
+
+def local_sgd_nodes(binding: "Binding", params_n, batches_nh, lr):
+    """:func:`local_sgd` on every node: node-stacked ``params_n`` and
+    ``batches_nh`` (leading ``[n, H, ...]``). A binding that can ``pack``
+    trains all nodes of a device in one packed program
+    (``[B, H, W, n*C]`` activations); any other runs
+    ``node_vmap(local_sgd)``."""
+    if sgd_path(binding) == "vmap":
+        return node_vmap(lambda p, b: local_sgd(binding, p, b, lr))(
+            params_n, batches_nh)
+    return node_blocks(lambda p, b: _packed_sgd(binding, lr, p, b))(
+        params_n, batches_nh)
+
+
+@scope("local_sgd")
+def _packed_sgd(binding: "Binding", lr, params_n, batches_nh):
+    """H SGD steps on all nodes at once: the gradient of the stacked loss
+    is each node's own gradient, so every node takes :func:`local_sgd`'s
+    step. The batches are packed once, before the scan.
+
+    Packed nodes share contractions at exact-zero weight, where a value
+    that is not finite would spread to its neighbours. So a node whose
+    state is not finite trains on zeros and comes out NaN, poisoned as
+    node-by-node training leaves it (there, leaves its loss never reaches
+    may stay finite); the other nodes' results are their own."""
+    from repro import resil   # local import: resil must stay core-free
+    ok = resil.node_finite(params_n) > 0
+
+    def keep(fill):
+        return lambda l: jnp.where(
+            ok.reshape((-1,) + (1,) * (l.ndim - 1)), l, fill)
+
+    batches_h = binding.pack(batches_nh)
+
+    def step(p, batch):
+        g = jax.grad(binding.loss)(p, batch)
+        p = jax.tree.map(lambda w, gg: (w - lr * gg).astype(w.dtype), p, g)
+        return p, None
+
+    params_n, _ = jax.lax.scan(step, jax.tree.map(keep(0), params_n),
+                               batches_h)
+    return jax.tree.map(keep(jnp.nan), params_n)
 
 
 @scope("gossip")
@@ -236,8 +295,12 @@ def _cnn_binding(cfg: CNNConfig) -> Binding:
         logits = cnn.head_apply(cfg, head, feats)
         return layers.softmax_xent(logits, batch["y"])
 
+    def pack(batches_nh):       # x [n, H, B, ..., C] -> [H, B, ..., n*C]
+        return {"x": cnn.pack_nodes(batches_nh["x"]),    # y -> [H, B, n]
+                "y": jnp.moveaxis(batches_nh["y"], 0, -1)}
+
     return Binding(cfg, lambda k: cnn.init_params(cfg, k), hk, loss,
-                   features, head_loss)
+                   features, head_loss, pack)
 
 
 # --------------------------------------------------------------------------

@@ -80,7 +80,7 @@ from typing import Any
 
 import numpy as np
 
-from .bindings import make_binding
+from .bindings import make_binding, sgd_path
 from .engine import SegmentEngine
 
 
@@ -260,7 +260,7 @@ class CacheEntry:
             batch_size=spec.batch_size,
             track_cluster=self.program.track_cluster,
             mixable_of=self.program.mixable_of, topo=spec.topo,
-            obs=spec.obs, mesh=spec.mesh)
+            obs=spec.obs, mesh=spec.mesh, sgd_path=sgd_path(self.binding))
 
     def setup(self, key):
         return self.program.setup(key)

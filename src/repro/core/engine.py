@@ -98,13 +98,18 @@ class SegmentEngine:
     Per-row arithmetic is identical either way; only reductions ACROSS
     rows (``round_bytes``/``round_s``/obs-frame scalars) may sum in a
     different order on a multi-device mesh.
+
+    ``sgd_path``: which local-SGD program the round functions build
+    (:func:`repro.core.bindings.sgd_path`), stated with ``nodes`` on each
+    ``compile`` span.
     """
 
     def __init__(self, round_fn: Callable, *, n: int, local_steps: int,
                  batch_size: int, net=None, warmup_fn: Callable | None = None,
                  track_cluster: bool = False, mixable_of: Callable | None = None,
-                 topo=None, obs=None, mesh=None):
+                 topo=None, obs=None, mesh=None, sgd_path: str | None = None):
         self._round = round_fn
+        self._sgd_path = sgd_path
         self._warm = warmup_fn if warmup_fn is not None else round_fn
         self._net = net
         self._topo = topo           # repro.topo.TopoConfig | None (static)
@@ -266,9 +271,10 @@ class SegmentEngine:
         donated — consumed either way.
 
         The call is a ``compile`` span (first trace of this program in
-        this process) or a ``dispatch`` span (async: trace + enqueue
-        only): a ``repro.*`` profiler annotation, and a ``tracer`` span
-        when a tracer is given (:func:`repro.obs.trace.span`).
+        this process; it states ``sgd_path`` and ``nodes``) or a
+        ``dispatch`` span (async: trace + enqueue only): a ``repro.*``
+        profiler annotation, and a ``tracer`` span when a tracer is given
+        (:func:`repro.obs.trace.span`).
         """
         key = (length, warmup)
         fn = self._compiled.get(key)
@@ -280,8 +286,10 @@ class SegmentEngine:
         if fresh:
             self._traced.add(trace_key)
             self.compile_count += 1
+        attrs = ({"sgd_path": self._sgd_path, "nodes": self._n}
+                 if fresh and self._sgd_path is not None else {})
         with span(tracer, "compile" if fresh else "dispatch",
-                  length=length, warmup=warmup):
+                  length=length, warmup=warmup, **attrs):
             return fn(carry, jnp.asarray(start, jnp.int32),
                       train_x, train_y)
 

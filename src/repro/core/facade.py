@@ -26,8 +26,10 @@ from repro import topo as topo_mod
 from repro.obs.trace import scope
 
 from . import split, topology
-from .bindings import (Binding, gossip_mix, local_sgd, node_head_matmul,
-                       node_matmul, node_vmap)
+from .bindings import (Binding, gossip_mix, node_head_matmul, node_matmul,
+                       node_vmap)
+# the round's local step: every node's H SGD steps at once
+from .bindings import local_sgd_nodes as local_sgd
 from .netwire import comm_info, masked_topology, sent_view
 from .state import FacadeState, freeze_inactive
 
@@ -175,11 +177,11 @@ def facade_round(fcfg: FacadeConfig, binding: Binding, state: FacadeState,
     if warmup:  # App. F: shared-head warmup trains head 0 everywhere
         new_cid = jnp.zeros((n,), jnp.int32)
 
-    # --- local training (step 2d) ---
-    def train_node(core, heads_k, cid, node_batches):
-        head = split.select_head(heads_k, cid)
-        params = split.merge_params(core, head)
-        params = local_sgd(binding, params, node_batches, fcfg.lr)
+    # --- local training (step 2d) on (core, selected head) ---
+    def pick(core, heads_k, cid):
+        return split.merge_params(core, split.select_head(heads_k, cid))
+
+    def put(params, heads_k, cid):
         new_core, new_head = split.split_params(params, binding.head_keys)
         if warmup:  # broadcast the trained head to every slot
             heads_k = split.stack_heads(new_head, k)
@@ -187,8 +189,9 @@ def facade_round(fcfg: FacadeConfig, binding: Binding, state: FacadeState,
             heads_k = split.set_head(heads_k, cid, new_head)
         return new_core, heads_k
 
-    new_cores, new_heads = node_vmap(train_node)(cores, heads, new_cid,
-                                                 batches)
+    params = local_sgd(binding, node_vmap(pick)(cores, heads, new_cid),
+                       batches, fcfg.lr)
+    new_cores, new_heads = node_vmap(put)(params, heads, new_cid)
 
     # --- communication accounting: each node pushes (core, head, cid) ---
     core_bytes = split.tree_size_bytes(

@@ -47,8 +47,6 @@ def group_norm(x, gamma, beta, groups: int, eps: float = 1e-5):
     xf = x.astype(jnp.float32)
     c = x.shape[-1]
     g = xf.reshape(x.shape[:-1] + (groups, c // groups))
-    mu = jnp.mean(g, axis=(-1, -2, -3, -4) if x.ndim == 4 else (-1,),
-                  keepdims=True)
     # NHWC: normalize over (H, W, channels-in-group)
     if x.ndim == 4:
         mu = jnp.mean(g, axis=(1, 2, 4), keepdims=True)

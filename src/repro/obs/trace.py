@@ -24,7 +24,9 @@ that was open over it. The names: ``repro.run`` (one
 experiment), ``repro.upload`` (the train arrays' host-to-device copy, stat
 ``bytes``; also the evaluator's test batches when it is built),
 ``repro.setup`` (initial state and carry), ``repro.compile`` /
-``repro.dispatch`` (a segment's first / later call), ``repro.drain``,
+``repro.dispatch`` (a segment's first / later call; ``compile`` states
+which local-SGD program it built, stats ``sgd_path`` ``"packed"`` or
+``"vmap"`` and ``nodes``), ``repro.drain``,
 ``repro.finalize``, ``repro.eval``, ``repro.record`` (comm log and
 cluster history), ``repro.ckpt.save``, ``repro.cache.entry``; serving adds
 ``repro.prefill`` / ``repro.decode``. Inside the compiled programs the
@@ -108,7 +110,9 @@ class Tracer:
     def rollup(self) -> dict:
         """Aggregate timing per span name: ``{name: {count, total_s}}``
         plus event counts — the ``RunManifest`` timing payload. Spans
-        that carry a ``bytes`` attr (``upload``) also total it."""
+        that carry a ``bytes`` attr (``upload``) also total it; spans that
+        carry ``sgd_path`` (``compile``) count programs per path,
+        ``{"packed": 1}``."""
         out: dict[str, dict] = {}
         for rec in self.spans:
             slot = out.setdefault(rec["name"],
@@ -117,6 +121,9 @@ class Tracer:
             slot["total_s"] += rec["dur_s"]
             if "bytes" in rec:
                 slot["bytes"] = slot.get("bytes", 0) + rec["bytes"]
+            if "sgd_path" in rec:
+                paths = slot.setdefault("sgd_path", {})
+                paths[rec["sgd_path"]] = paths.get(rec["sgd_path"], 0) + 1
         ev: dict[str, int] = {}
         for rec in self.events:
             ev[rec["name"]] = ev.get(rec["name"], 0) + 1
