@@ -144,6 +144,19 @@ def sgd_path(binding: "Binding") -> str:
     return "vmap" if binding.pack is None else "packed"
 
 
+def compile_stats(binding: "Binding", n: int) -> dict:
+    """What a ``compile`` span states of the local-SGD program built for
+    ``binding`` with ``n`` nodes on each device: ``sgd_path``, ``model``
+    (the config's name) and, on the packed path, ``pack_groups``: each
+    packed convolution's nodes to a group, in forward order (``"1,1,1"``
+    for GN-LeNet at width 32)."""
+    stats = {"sgd_path": sgd_path(binding), "model": binding.cfg.name}
+    if stats["sgd_path"] == "packed":
+        stats["pack_groups"] = ",".join(
+            str(g) for g in cnn.pack_groups(binding.cfg, n))
+    return stats
+
+
 def local_sgd_nodes(binding: "Binding", params_n, batches_nh, lr):
     """:func:`local_sgd` on every node: node-stacked ``params_n`` and
     ``batches_nh`` (leading ``[n, H, ...]``). A binding that can ``pack``

@@ -80,7 +80,7 @@ from typing import Any
 
 import numpy as np
 
-from .bindings import make_binding, sgd_path
+from .bindings import compile_stats, make_binding
 from .engine import SegmentEngine
 
 
@@ -260,7 +260,8 @@ class CacheEntry:
             batch_size=spec.batch_size,
             track_cluster=self.program.track_cluster,
             mixable_of=self.program.mixable_of, topo=spec.topo,
-            obs=spec.obs, mesh=spec.mesh, sgd_path=sgd_path(self.binding))
+            obs=spec.obs, mesh=spec.mesh,
+            compile_stats=lambda n: compile_stats(self.binding, n))
 
     def setup(self, key):
         return self.program.setup(key)

@@ -99,17 +99,18 @@ class SegmentEngine:
     rows (``round_bytes``/``round_s``/obs-frame scalars) may sum in a
     different order on a multi-device mesh.
 
-    ``sgd_path``: which local-SGD program the round functions build
-    (:func:`repro.core.bindings.sgd_path`), stated with ``nodes`` on each
-    ``compile`` span.
+    ``compile_stats``: given the nodes each device holds, what the
+    ``compile`` span states of the program besides ``nodes``
+    (:func:`repro.core.bindings.compile_stats`: ``sgd_path``, ``model``,
+    ``pack_groups``).
     """
 
     def __init__(self, round_fn: Callable, *, n: int, local_steps: int,
                  batch_size: int, net=None, warmup_fn: Callable | None = None,
                  track_cluster: bool = False, mixable_of: Callable | None = None,
-                 topo=None, obs=None, mesh=None, sgd_path: str | None = None):
+                 topo=None, obs=None, mesh=None,
+                 compile_stats: Callable | None = None):
         self._round = round_fn
-        self._sgd_path = sgd_path
         self._warm = warmup_fn if warmup_fn is not None else round_fn
         self._net = net
         self._topo = topo           # repro.topo.TopoConfig | None (static)
@@ -131,6 +132,9 @@ class SegmentEngine:
                 f"mesh of {self._mesh.size} devices must divide n={n} "
                 "nodes evenly: the carry's node axis is row-sharded in "
                 "equal blocks (pad the node count or shrink the mesh)")
+        per_device = n if self._mesh is None else n // self._mesh.size
+        self._stats = None if compile_stats is None else {
+            **compile_stats(per_device), "nodes": n}
         self._compiled: dict[tuple[int, bool], Callable] = {}
         # compile_count tracks XLA compiles, not just fresh (length, warmup)
         # builds: a cached jitted segment RETRACES when the train arrays
@@ -271,7 +275,7 @@ class SegmentEngine:
         donated — consumed either way.
 
         The call is a ``compile`` span (first trace of this program in
-        this process; it states ``sgd_path`` and ``nodes``) or a
+        this process; it states ``compile_stats`` and ``nodes``) or a
         ``dispatch`` span (async: trace + enqueue only): a ``repro.*``
         profiler annotation, and a ``tracer`` span when a tracer is given
         (:func:`repro.obs.trace.span`).
@@ -286,8 +290,7 @@ class SegmentEngine:
         if fresh:
             self._traced.add(trace_key)
             self.compile_count += 1
-        attrs = ({"sgd_path": self._sgd_path, "nodes": self._n}
-                 if fresh and self._sgd_path is not None else {})
+        attrs = self._stats if fresh and self._stats is not None else {}
         with span(tracer, "compile" if fresh else "dispatch",
                   length=length, warmup=warmup, **attrs):
             return fn(carry, jnp.asarray(start, jnp.int32),

@@ -104,7 +104,7 @@ class CNNConfig:
     image_size: int = 32
     channels: int = 3
     n_classes: int = 10
-    width: int = 32  # base conv width
+    width: int = 32  # base conv width: lenet (w, w, 2w); resnet8 (w/2, w, 2w)
     groups: int = 2  # group-norm groups
     head_blocks: int = 0  # resnet8: how many trailing blocks join the head
     dtype: str = "float32"

@@ -1,6 +1,12 @@
 """The paper's experimental models: GN-LeNet (CIFAR-10/Imagenette runs) and
 ResNet8 (Flickr-Mammals runs), both with GroupNorm as in Hsieh et al. [41].
 
+GN-LeNet is the published network (Hsieh et al., arXiv:1910.00189; the
+FACADE authors' decentralizepy ``GN_LeNet``): three 5x5 "same"
+convolutions of widths (w, w, 2w), each followed by GroupNorm, ReLU and a
+2x2 max-pool, then one FC; at w = 32 on 32x32 images, 32/32/64 channels
+and an FC of 1,024 -> 10.
+
 FACADE head split (paper Sec. V-A "Models"):
   * GN-LeNet  — head = final fully-connected layer.
   * ResNet8   — head = last two basic blocks + final FC.
@@ -51,6 +57,12 @@ def pack_nodes(x):
     return jnp.moveaxis(x, 0, -2).reshape(x.shape[1:-1] + (n * c,))
 
 
+def pack_group(n: int, cout: int) -> int:
+    """Nodes to a group of :func:`_conv2d_nodes` for ``n`` nodes of
+    ``cout`` output channels."""
+    return math.gcd(n, LANES // cout) if cout * PAD_FLOOR <= LANES else 1
+
+
 def _conv2d_nodes(x, w, stride: int = 1):
     """``x [B,H,W,n*Cin]`` by node-stacked ``w [n,kh,kw,Cin,Cout]``: one
     grouped convolution of ``g`` nodes to a group, over block-diagonal
@@ -63,7 +75,7 @@ def _conv2d_nodes(x, w, stride: int = 1):
     them as well ran faster still, but its 10 MB more TPU code, resident
     in HBM, raised peak device memory by 2% (PERF.md, section 6)."""
     n, kh, kw, ci, co = w.shape
-    g = math.gcd(n, LANES // co) if co * PAD_FLOOR <= LANES else 1
+    g = pack_group(n, co)
     w = jnp.moveaxis(w.reshape(n // g, g, kh, kw, ci, co), (0, 1), (3, 4))
     eye = jnp.eye(g, dtype=bool)[:, None, None, :, None]
     w = jnp.where(eye, w[:, :, None], 0).reshape(kh, kw, g * ci, n * co)
@@ -126,16 +138,16 @@ def _gn_params(c, dtype):
 # ==========================================================================
 # GN-LeNet
 def init_lenet(cfg: CNNConfig, key):
-    w = cfg.width
+    w, k = cfg.width, 5
     ks = jax.random.split(key, 4)
-    feat = (cfg.image_size // 8) ** 2 * w
+    feat = (cfg.image_size // 8) ** 2 * 2 * w
     return {
-        "conv1": {"w": conv_init(ks[0], 3, 3, cfg.channels, w, cfg.dt),
+        "conv1": {"w": conv_init(ks[0], k, k, cfg.channels, w, cfg.dt),
                   "gn": _gn_params(w, cfg.dt)},
-        "conv2": {"w": conv_init(ks[1], 3, 3, w, w, cfg.dt),
+        "conv2": {"w": conv_init(ks[1], k, k, w, w, cfg.dt),
                   "gn": _gn_params(w, cfg.dt)},
-        "conv3": {"w": conv_init(ks[2], 3, 3, w, w, cfg.dt),
-                  "gn": _gn_params(w, cfg.dt)},
+        "conv3": {"w": conv_init(ks[2], k, k, w, 2 * w, cfg.dt),
+                  "gn": _gn_params(2 * w, cfg.dt)},
         "fc": {"w": layers.dense_init(ks[3], feat, cfg.n_classes, cfg.dt),
                "b": jnp.zeros((cfg.n_classes,), cfg.dt)},
     }
@@ -249,6 +261,26 @@ def head_keys(cfg: CNNConfig):
 def forward(cfg: CNNConfig, params, x, nn: Layers = NODE):
     return (lenet_forward(cfg, params, x, nn) if cfg.kind == "lenet"
             else resnet8_forward(cfg, params, x, nn))
+
+
+def _conv_weights(params):
+    """The convolution kernels of ``params`` in forward order (the order
+    the init functions build them in)."""
+    for v in params.values():
+        if isinstance(v, dict):
+            yield from _conv_weights(v)
+        elif v.ndim == 4:
+            yield v
+
+
+def pack_groups(cfg: CNNConfig, n: int) -> tuple[int, ...]:
+    """Each convolution's nodes to a group on the packed path, in forward
+    order, for ``n`` nodes on a device: ``(1, 1, 1)`` for GN-LeNet at
+    width 32."""
+    shapes = jax.eval_shape(
+        lambda k: list(_conv_weights(init_params(cfg, k))),
+        jax.random.PRNGKey(0))
+    return tuple(pack_group(n, s.shape[-1]) for s in shapes)
 
 
 def loss_fn(cfg: CNNConfig, params, batch):

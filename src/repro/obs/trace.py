@@ -26,7 +26,9 @@ experiment), ``repro.upload`` (the train arrays' host-to-device copy, stat
 ``repro.setup`` (initial state and carry), ``repro.compile`` /
 ``repro.dispatch`` (a segment's first / later call; ``compile`` states
 which local-SGD program it built, stats ``sgd_path`` ``"packed"`` or
-``"vmap"`` and ``nodes``), ``repro.drain``,
+``"vmap"``, ``model`` (the config's name), ``nodes`` and, packed,
+``pack_groups``: each convolution's nodes to a group, ``"1,1,1"``),
+``repro.drain``,
 ``repro.finalize``, ``repro.eval``, ``repro.record`` (comm log and
 cluster history), ``repro.ckpt.save``, ``repro.cache.entry``; serving adds
 ``repro.prefill`` / ``repro.decode``. Inside the compiled programs the
@@ -63,6 +65,10 @@ import time
 from typing import Any
 
 import jax
+
+
+# span stats that say what a span built; ``Tracer.rollup`` counts each value
+STATED = ("sgd_path", "model", "pack_groups")
 
 
 class Tracer:
@@ -111,8 +117,8 @@ class Tracer:
         """Aggregate timing per span name: ``{name: {count, total_s}}``
         plus event counts — the ``RunManifest`` timing payload. Spans
         that carry a ``bytes`` attr (``upload``) also total it; spans that
-        carry ``sgd_path`` (``compile``) count programs per path,
-        ``{"packed": 1}``."""
+        state what they built (``compile``: ``sgd_path``, ``model``,
+        ``pack_groups``) count programs per value, ``{"packed": 1}``."""
         out: dict[str, dict] = {}
         for rec in self.spans:
             slot = out.setdefault(rec["name"],
@@ -121,9 +127,10 @@ class Tracer:
             slot["total_s"] += rec["dur_s"]
             if "bytes" in rec:
                 slot["bytes"] = slot.get("bytes", 0) + rec["bytes"]
-            if "sgd_path" in rec:
-                paths = slot.setdefault("sgd_path", {})
-                paths[rec["sgd_path"]] = paths.get(rec["sgd_path"], 0) + 1
+            for stat in STATED:
+                if stat in rec:
+                    seen = slot.setdefault(stat, {})
+                    seen[rec[stat]] = seen.get(rec[stat], 0) + 1
         ev: dict[str, int] = {}
         for rec in self.events:
             ev[rec["name"]] = ev.get(rec["name"], 0) + 1

@@ -119,7 +119,7 @@ def test_round_on_the_packed_path_equals_node_by_node(algo, model):
 
 
 def test_a_node_that_is_not_finite_poisons_no_other():
-    """GN-LeNet at width 8 packs all four nodes into one group."""
+    """GN-LeNet at width 4 packs all four nodes into one group."""
     binding = bindings.make_binding(LENET)
     params = _stacked(binding, 4)
     params = jax.tree.map(lambda l: l.at[1].set(jnp.nan), params)
@@ -184,4 +184,5 @@ def test_compile_span_states_the_sgd_path(monkeypatch):
     assert "sgd_path" not in spans["dispatch"]
     compiles = [st for name, st in seen if name == "repro.compile"]
     assert compiles == [{"length": 1, "warmup": False,
-                         "sgd_path": "packed", "nodes": 4}]
+                         "sgd_path": "packed", "model": LENET.name,
+                         "pack_groups": "4,4,4", "nodes": 4}]

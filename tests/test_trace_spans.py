@@ -1,5 +1,6 @@
 """Program spans on the profiler's clock, the host-to-device byte counter,
-and the stage scopes inside the segment program.
+the stats a ``compile`` span states of what it built, and the stage
+scopes inside the segment program.
 
 Pins: :func:`repro.obs.trace.span` always enters a
 ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (with or without a
@@ -20,10 +21,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.facade_paper import lenet
+from repro.configs.facade_paper import lenet, resnet8
+from repro.core.bindings import compile_stats, make_binding
 from repro.core.cache import CacheEntry, EngineSpec
 from repro.core.runner import host_bytes, run_experiment
 from repro.data.synthetic import SynthSpec, make_clustered_data
+from repro.models.base import get_config
 from repro.obs import Obs, Tracer, span
 
 pytestmark = pytest.mark.tier0
@@ -99,6 +102,34 @@ def test_rollup_totals_the_bytes_spans_carry():
     roll = tr.rollup()["spans"]
     assert roll["upload"]["count"] == 2 and roll["upload"]["bytes"] == 12
     assert set(roll["drain"]) == {"count", "total_s"}
+
+
+def test_rollup_counts_what_the_compile_spans_built():
+    tr = Tracer()
+    for groups in ("1,1,1", "1,1,1", "4,4,4"):
+        with tr.span("compile", sgd_path="packed", model="gn-lenet",
+                     pack_groups=groups, nodes=32):
+            pass
+    roll = tr.rollup()["spans"]["compile"]
+    assert roll["sgd_path"] == {"packed": 3}
+    assert roll["model"] == {"gn-lenet": 3}
+    assert roll["pack_groups"] == {"1,1,1": 2, "4,4,4": 1}
+
+
+@pytest.mark.parametrize("cfg,n,want", [
+    (lenet(), 32, {"sgd_path": "packed", "model": "gn-lenet",
+                   "pack_groups": "1,1,1"}),
+    (lenet(smoke=True), 4, {"sgd_path": "packed", "model": "gn-lenet-smoke",
+                            "pack_groups": "4,4,4"}),
+    (resnet8(), 32, {"sgd_path": "packed", "model": "resnet8",
+                     "pack_groups": "8,8,8,1,1,1,1,1,1"}),
+    (get_config("llama3.2-1b", smoke=True), 4,
+     {"sgd_path": "vmap", "model": "llama3.2-1b-smoke"}),
+], ids=["gn-lenet", "gn-lenet-smoke", "resnet8", "llama"])
+def test_compile_stats_name_the_model_and_its_pack_groups(cfg, n, want):
+    """GN-LeNet's convolutions are 32 and 64 channels wide, so each keeps
+    one node to a group; ResNet8's 16-channel stem and block1 take eight."""
+    assert compile_stats(make_binding(cfg), n) == want
 
 
 # ------------------------------------------------- the drivers' spans --
