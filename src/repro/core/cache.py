@@ -17,13 +17,21 @@ those compiles dominate wall-clock.
   therefore shared by every run of the cell;
 * evaluators, cached cache-wide on ``(model cfg, eval batch, content
   fingerprint of the eval split)`` — independent of algorithm and netsim
-  preset, so a grid of presets over one dataset compiles ONE evaluator.
+  preset, so a grid of presets over one dataset compiles ONE evaluator;
+* the train split on the device (:meth:`EngineCache.train_data`): ONE
+  slot, keyed on the dataset object (held weakly), the identity of its
+  ``train_x`` / ``train_y`` arrays and the node-mesh shape, so every run
+  over one dataset after the first moves no bytes. A run over another
+  dataset or placement releases the old arrays before it uploads, so
+  never more than one train set is resident.
 
 Cache-key contract: every knob that changes a compiled program or the
 round/eval arithmetic MUST be a field of :class:`EngineSpec`; only the
 experiment seed (PRNG) and the data may vary within an entry. A changed
 eval split changes the fingerprint, never silently reuses a stale
-evaluator; train data is passed per call and never cached. ``rounds`` and
+evaluator; a reassigned ``train_x`` / ``train_y`` is a new upload. A
+dataset's arrays are never changed IN PLACE after first use (neither
+split): build a new dataset, or assign a new array. ``rounds`` and
 ``eval_every`` are deliberately NOT key fields — segment programs are
 keyed per ``(length, warmup)`` inside the engine, so different eval
 schedules share an entry safely. The netsim-v2 knobs (``burst`` /
@@ -42,7 +50,9 @@ do (``tests/test_obs.py`` pins both directions).
 Donation caveat: segment programs donate their input :class:`EngineCarry`
 buffers. Reusing a cached engine across runs is safe precisely because
 each run builds a FRESH carry from its own seed; never feed a consumed
-carry back into ``run_segment``.
+carry back into ``run_segment``. Only the carry is donated: the segment
+programs, the legacy per-round loop and checkpoint resume only READ the
+train arrays, which is why the kept train split can be reused as is.
 
 Always-warm extensions (ROADMAP Open Item 5a):
 
@@ -78,7 +88,11 @@ import pathlib
 import weakref
 from typing import Any
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from repro.obs.trace import span
 
 from .bindings import compile_stats, make_binding
 from .engine import SegmentEngine
@@ -202,6 +216,13 @@ def _reset_jax_cache() -> None:
     compilation_cache.reset_cache()
 
 
+def host_bytes(*arrays) -> int:
+    """Bytes a host-to-device copy of ``arrays`` moves: each host array's
+    ``nbytes``; an array already on the device counts 0."""
+    return sum(0 if isinstance(a, jax.Array) else np.asarray(a).nbytes
+               for a in arrays)
+
+
 _FP_MEMO: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
@@ -211,9 +232,12 @@ def data_fingerprint(dataset) -> str:
 
     Memoized per dataset OBJECT (weakly, so the memo never pins data):
     sweeps look the same dataset up once per run, and re-hashing the eval
-    split every time would be pure overhead. The flip side: mutating a
-    dataset's eval arrays IN PLACE after first use is not detected —
-    build a new dataset instead (the synthetic pipeline always does).
+    split every time would be pure overhead. The memo needs a hashable
+    dataset: ``ClusteredDataset`` hashes by identity for this, as the
+    train slot of :meth:`EngineCache.train_data` does. The flip side:
+    mutating a dataset's arrays IN PLACE after first use — the eval split
+    here, the train split there — is not detected: build a new dataset,
+    or assign a new array, instead (the synthetic pipeline always builds).
     """
     try:
         return _FP_MEMO[dataset]
@@ -276,7 +300,10 @@ class EngineCache:
 
     ``entry(spec)`` returns the cell's entry, building it on first use;
     ``evaluator(binding, dataset, batch)`` returns the (cfg, batch,
-    data-fingerprint)-keyed evaluator. ``compile_count`` totals every
+    data-fingerprint)-keyed evaluator; ``train_data(entry, dataset)`` the
+    dataset's train split on the device, kept in one slot across runs
+    (``data_hits`` / ``data_uploads`` count how it was served).
+    ``compile_count`` totals every
     compiled program the cache EVER built — segment builds plus evaluator
     builds, monotone across LRU evictions — which is what sweep smokes
     assert stays flat after each cell's first run.
@@ -310,6 +337,11 @@ class EngineCache:
         self.misses = 0          # entry() had to build
         self.evictions = 0       # entries dropped by the LRU bound
         self.evaluator_builds = 0
+        # one train split on the device: dataset -> (weakrefs to its
+        # train_x / train_y, mesh shape, placed arrays); at most one item
+        self._train: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
+        self.data_hits = 0       # train_data() served the kept arrays
+        self.data_uploads = 0    # train_data() copied the train split
         self.max_entries = max_entries
         self._evicted_compiles = 0   # keeps compile_count monotone
         self.persist_dir = (attach_persist_dir(persist_dir)
@@ -394,6 +426,42 @@ class EngineCache:
             self.evaluator_builds += 1
         return ev
 
+    def train_data(self, entry: CacheEntry, dataset, tracer=None):
+        """``dataset``'s node-stacked train arrays placed for ``entry``'s
+        engine (on its node mesh when it has one), inside the ``upload``
+        span, whose ``bytes`` stat is what was moved: 0 when the slot
+        already holds this dataset object, its current ``train_x`` /
+        ``train_y`` objects (compared with ``is``: a reassigned array is a
+        miss) and the same mesh shape. A miss releases the slot's arrays
+        BEFORE it uploads, so at most one train set is resident. Emits a
+        ``data.hit`` / ``data.miss`` tracer event. A dataset that cannot be
+        held weakly (unhashable, or arrays without weak references) is
+        uploaded on every call, as before."""
+        x, y, mesh = dataset.train_x, dataset.train_y, entry.spec.mesh
+        try:
+            kept = self._train.get(dataset)
+        except TypeError:                  # unhashable: never kept
+            kept = None
+        if kept is not None and kept[0]() is x and kept[1]() is y \
+                and kept[2] == mesh:
+            self.data_hits += 1
+            if tracer is not None:
+                tracer.event("data.hit")
+            with span(tracer, "upload", bytes=0):
+                return kept[3]
+        self._train.clear()
+        self.data_uploads += 1
+        if tracer is not None:
+            tracer.event("data.miss")
+        with span(tracer, "upload", bytes=host_bytes(x, y)):
+            placed = entry.engine.place_data(jnp.asarray(x), jnp.asarray(y))
+        try:
+            self._train[dataset] = (weakref.ref(x), weakref.ref(y), mesh,
+                                    placed)
+        except TypeError:
+            pass
+        return placed
+
     @property
     def compile_count(self) -> int:
         return (sum(e.compile_count for e in self._entries.values())
@@ -404,6 +472,8 @@ class EngineCache:
                 "misses": self.misses, "evictions": self.evictions,
                 "compiles": self.compile_count,
                 "evaluator_builds": self.evaluator_builds,
+                "data_hits": self.data_hits,
+                "data_uploads": self.data_uploads,
                 "max_entries": self.max_entries,
                 "persist_dir": self.persist_dir}
 

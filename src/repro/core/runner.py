@@ -48,7 +48,7 @@ from .baselines import (DACConfig, DeprlConfig, DpsgdConfig, ELConfig,
                         dac_round, deprl_round, dpsgd_round, el_round,
                         init_dac_extra)
 from .bindings import Binding
-from .cache import EngineCache, EngineSpec
+from .cache import EngineCache, EngineSpec, host_bytes  # noqa: F401 (re-export)
 from .engine import segment_plan
 from .state import EngineCarry, init_baseline_state, init_facade_state
 
@@ -415,9 +415,13 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
 
     ``cache``: optional :class:`repro.core.cache.EngineCache` shared across
     calls — a sweep of seeds over one config then pays the XLA compiles
-    once (see :mod:`repro.sweep`). ``None`` (the default) uses a fresh
-    private cache, which is bit-identical to the historical
-    build-everything-per-call behavior.
+    once (see :mod:`repro.sweep`), and every call after the first over the
+    same dataset object reuses its train split already on the device
+    (:meth:`~repro.core.cache.EngineCache.train_data`). So a dataset's
+    arrays, train and eval split alike, must not be changed in place
+    after first use: build a new dataset, or assign a new array. ``None``
+    (the default) uses a fresh private cache, which is bit-identical to
+    the historical build-everything-per-call behavior.
 
     ``obs``: optional :class:`repro.obs.Obs` — in-scan per-round metric
     frames (when ``obs.config`` is set), nested tracer spans around
@@ -503,13 +507,10 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
     # evict the engine whose donated carry/segment programs are in flight
     with prof, cache.pin(spec), \
             span(tracer, "run", algo=algo, seed=seed, engine=engine):
-        # commit the node-stacked train arrays to the device, on the
-        # entry's node mesh when there is one, so every segment reads its
-        # shard locally
-        with span(tracer, "upload",
-                  bytes=host_bytes(dataset.train_x, dataset.train_y)):
-            train_x, train_y = entry.engine.place_data(
-                jnp.asarray(dataset.train_x), jnp.asarray(dataset.train_y))
+        # the node-stacked train arrays on the device, on the entry's node
+        # mesh when there is one so every segment reads its shard locally;
+        # the cache keeps them there for its next run over this dataset
+        train_x, train_y = cache.train_data(entry, dataset, tracer=tracer)
         with span(tracer, "setup"):
             setup = entry.setup(k_init)
         builds0 = cache.evaluator_builds
@@ -565,13 +566,6 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
             timing=obs.tracer.rollup(), cache=cache.stats(),
             health=health))
     return hist.result(algo)
-
-
-def host_bytes(*arrays) -> int:
-    """Bytes a host-to-device copy of ``arrays`` moves: each host array's
-    ``nbytes``; an array already on the device counts 0."""
-    return sum(0 if isinstance(a, jax.Array) else np.asarray(a).nbytes
-               for a in arrays)
 
 
 # --------------------------------------------------------------------------

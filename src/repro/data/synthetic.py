@@ -84,8 +84,17 @@ def _sample(rng, protos, labels, spec: SynthSpec):
     return np.clip(x, -2.0, 2.0).astype(np.float32)
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class ClusteredDataset:
+    """Node-stacked train split and per-cluster eval split.
+
+    Hashed and compared by identity (``eq=False``): the program keeps
+    per-dataset work per OBJECT — the eval split's content fingerprint
+    (``repro.core.cache.data_fingerprint``) and the train split on the
+    device (``EngineCache.train_data``) — so the arrays must not be
+    changed in place after first use. Build a new dataset, or assign a
+    new array (``ds.train_x = ...``), instead.
+    """
     train_x: np.ndarray      # [n_nodes, N, H, W, C]
     train_y: np.ndarray      # [n_nodes, N]
     test_x: list             # per cluster: [M, H, W, C]

@@ -215,6 +215,30 @@ def test_evaluator_cache_keyed_on_data_content(tiny_ds):
     assert cache.evaluator_builds == 2           # warm again
 
 
+def test_data_fingerprint_hashes_a_dataset_once(tiny_ds, monkeypatch):
+    """A ``ClusteredDataset`` hashes by identity, so the fingerprint's weak
+    per-object memo holds: its content is hashed on first use only, and
+    a rebuilt dataset object (same content) is hashed afresh."""
+    import hashlib
+    import types
+
+    from repro.core import cache as cache_mod
+
+    spec = dataclasses.replace(tiny_ds.spec, seed=tiny_ds.spec.seed + 2)
+    ds = make_clustered_data(spec, cluster_sizes=(3, 1),
+                             transforms=("rot0", "rot180"))
+    hashed = []
+    monkeypatch.setattr(cache_mod, "hashlib", types.SimpleNamespace(
+        sha1=lambda: hashed.append(1) or hashlib.sha1()))
+    assert hash(ds) == object.__hash__(ds) and ds != dataclasses.replace(ds)
+    fp = data_fingerprint(ds)
+    for _ in range(3):
+        assert data_fingerprint(ds) == fp
+    assert len(hashed) == 1
+    assert data_fingerprint(dataclasses.replace(ds)) == fp
+    assert len(hashed) == 2
+
+
 def test_compile_count_counts_retraces_on_new_train_shapes(tiny_ds):
     """A same-spec cell fed a different train shape RETRACES the cached
     jitted segment program; the compile counter must count that, or
