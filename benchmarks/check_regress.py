@@ -3,8 +3,8 @@
 ``common.write_bench`` appends every ``BENCH_*`` payload to
 ``results/bench/TRAJECTORY.jsonl``; this suite diffs each benchmark's
 LATEST record against its PREVIOUS one under per-metric tolerance gates,
-so a perf claim from PRs 2-9 (engine speedup, obs overhead, pipeline
-drain, sharding linearity, warm start) can't silently rot between runs.
+so a perf claim from PRs 2-9 (engine speedup, obs overhead, sharding
+linearity, warm start) can't silently rot between runs.
 
 Semantics:
 
@@ -66,10 +66,6 @@ GATES: "dict[str, tuple]" = {
     ),
     "throughput": (
         Gate("min_speedup", "higher"),
-    ),
-    "pipeline": (
-        Gate("min_drain_wait_reduction", "higher",
-             rel_tol=0.0, abs_tol=0.15),
     ),
     "scale": (
         Gate("linear_frac", "higher", rel_tol=0.0, abs_tol=0.15),

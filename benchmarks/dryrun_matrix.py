@@ -188,29 +188,6 @@ def run(quick: bool = True) -> dict:
               f"{shard_rec['bytes_parity']}, acc maxdiff "
               f"{shard_rec['acc_maxdiff']:.4f})")
 
-    # pipeline smoke: pipeline=True bit-parity with the serialized driver
-    try:
-        from . import pipeline as pipeline_bench
-        pipe_rec = pipeline_bench.smoke()
-    except Exception as e:
-        pipe_rec = {"status": "fail", "error": repr(e)}
-        print(f"pipeline smoke: FAIL ({e!r})")
-    else:
-        print(f"pipeline smoke: {pipe_rec['status']} "
-              f"({pipe_rec['total_bytes']/1e3:.1f} KB)")
-
-    # pipeline+ckpt smoke: a checkpointed pipelined run matches serialized
-    # and leaves a resumable archive behind
-    try:
-        from . import pipeline as pipeline_bench
-        pipeckpt_rec = pipeline_bench.smoke_ckpt()
-    except Exception as e:
-        pipeckpt_rec = {"status": "fail", "error": repr(e)}
-        print(f"pipeline+ckpt smoke: FAIL ({e!r})")
-    else:
-        print(f"pipeline+ckpt smoke: {pipeckpt_rec['status']} "
-              f"(ckpt written {pipeckpt_rec['ckpt_written']})")
-
     recs = [r for r in load("dryrun_*.jsonl") if r.get("tag", "") == ""]
     if not recs:
         print("no dry-run records; run `python -m repro.launch.dryrun --all` "
@@ -220,9 +197,7 @@ def run(quick: bool = True) -> dict:
                 "topo_smoke": topo_rec, "obs_smoke": obs_rec,
                 "health_smoke": health_rec,
                 "resil_smoke": resil_rec, "ckpt_smoke": ckpt_rec,
-                "persist_smoke": warm_rec, "shard_smoke": shard_rec,
-                "pipeline_smoke": pipe_rec,
-                "pipeline_ckpt_smoke": pipeckpt_rec}
+                "persist_smoke": warm_rec, "shard_smoke": shard_rec}
     rows = []
     ok = fail = skip = 0
     for r in sorted(recs, key=lambda r: (r["arch"], r["shape"], r["mesh"])):
@@ -252,9 +227,7 @@ def run(quick: bool = True) -> dict:
                "topo_smoke": topo_rec, "obs_smoke": obs_rec,
                "health_smoke": health_rec,
                "resil_smoke": resil_rec, "ckpt_smoke": ckpt_rec,
-               "persist_smoke": warm_rec, "shard_smoke": shard_rec,
-               "pipeline_smoke": pipe_rec,
-               "pipeline_ckpt_smoke": pipeckpt_rec}
+               "persist_smoke": warm_rec, "shard_smoke": shard_rec}
     common.save("dryrun_matrix", payload)
     return payload
 

@@ -189,7 +189,9 @@ def smoke_resume() -> dict:
     class _Killed(Exception):
         pass
 
-    orig = engine_mod.SegmentEngine.run_segment
+    # die draining the second segment: the first one's checkpoint has
+    # landed by then
+    orig = engine_mod.SegmentEngine.drain
     calls = {"n": 0}
 
     def killer(self, *a, **k):
@@ -198,14 +200,14 @@ def smoke_resume() -> dict:
         calls["n"] += 1
         return orig(self, *a, **k)
 
-    engine_mod.SegmentEngine.run_segment = killer
+    engine_mod.SegmentEngine.drain = killer
     try:
         run_experiment("facade", cfg, ds, ckpt=ck, **kw)
         killed = False                      # killer never fired: bad plan
     except _Killed:
         killed = True
     finally:
-        engine_mod.SegmentEngine.run_segment = orig
+        engine_mod.SegmentEngine.drain = orig
     got = run_experiment("facade", cfg, ds, ckpt=ck, **kw)
 
     metrics = (list(ref.final_acc) == list(got.final_acc)

@@ -18,7 +18,7 @@ import time
 from . import (check_regress, churn_resilience, color_shift, comm_cost,
                dryrun_matrix, fair_accuracy, fairness_dp_eo, fault_tolerance,
                k_sensitivity, kernel_bench, label_skew, obs_overhead,
-               percluster_accuracy, pipeline, round_throughput, scale_curve,
+               percluster_accuracy, round_throughput, scale_curve,
                seed_sweep, settlement, topo_adapt, warm_start,
                warmup_ablation)
 
@@ -36,7 +36,6 @@ SUITES = {
     "resil": fault_tolerance,                     # faults + robust gossip
     "topo_adapt": topo_adapt,                     # adaptive topology policies
     "round_throughput": round_throughput,         # segment engine rounds/sec
-    "pipeline": pipeline,                         # double-buffered dispatch
     "seed_sweep": seed_sweep,                     # compile-cache sweep vs naive
     "warm_start": warm_start,                     # persistent XLA cache
     "scale_curve": scale_curve,                   # sharded engine scaling

@@ -14,7 +14,7 @@ compile, whatever the cache already holds), ``fill`` with it on (makes
 sure the run's executables are on disk), and ``warm`` with it on, which
 deserializes them and reaches its first segment dispatch measurably
 faster. Each child reports ``first_dispatch_s`` (cache-entry build +
-first ``run_segment``, i.e. time to first useful device work) and its
+the run's one segment, i.e. time to first useful device work) and its
 tracer ``compile`` span total.
 
 The children each need the accelerator, and a chip belongs to one

@@ -8,8 +8,7 @@ Drives :func:`repro.core.runner.run_experiment` exactly as a user does
 model widths, in ONE process (a chip belongs to one process at a time):
 
 (a) FACADE on GN-LeNet (32x32, width 32, 10 classes), 32 nodes split
-    30:2, 80 rounds in two segments — once serialized, once with
-    ``pipeline=True``, through one ``EngineCache``;
+    30:2, 80 rounds in two segments — twice, through one ``EngineCache``;
 (b) Epidemic Learning in the same shape (the baseline path);
 (c) FACADE on ResNet8 (64x64, width 32, 41 classes), 32 nodes, 40 rounds.
 
@@ -111,11 +110,11 @@ def peak_bytes():
 
 
 def run_phase(name: str, algo: str, cfg, ds, *, rounds: int,
-              eval_every: int, pipelined_second: bool = False) -> dict:
+              eval_every: int) -> dict:
     """Two passes of one run through one ``EngineCache``: a cold pass
-    (compiles) and a warm pass (``pipeline=True`` when
-    ``pipelined_second``) that must compile nothing and agree with the
-    cold pass exactly. Raises on any failed check; returns the readings."""
+    (compiles) and a warm pass that must compile nothing and agree with
+    the cold pass exactly. Raises on any failed check; returns the
+    readings."""
     from repro.core.cache import EngineCache
     from repro.core.engine import segment_plan
     from repro.core.runner import run_experiment
@@ -139,20 +138,17 @@ def run_phase(name: str, algo: str, cfg, ds, *, rounds: int,
     c1 = cache.compile_count
     t0 = time.perf_counter()
     warm = run_experiment(algo, cfg, ds, rounds=rounds,
-                          eval_every=eval_every, cache=cache,
-                          pipeline=pipelined_second)
+                          eval_every=eval_every, cache=cache)
     steady_s = time.perf_counter() - t0
     expect(cache.compile_count == c1,
            f"{name}: warm pass compiled {cache.compile_count - c1} programs")
     check_run(warm, rounds=rounds, per_round=per_round, where=f"{name} warm")
-    same_run(cold, warm, f"{name} cold vs "
-             f"{'pipelined' if pipelined_second else 'warm'}")
+    same_run(cold, warm, f"{name} cold vs warm")
 
     return {"phase": name, "algo": algo, "model": cfg.name,
             "nodes": ds.n_nodes, "rounds": rounds,
             "cold_s": cold_s, "steady_s": steady_s,
             "compile_s": cold_s - steady_s,
-            "warm_pass": "pipelined" if pipelined_second else "serialized",
             "final_acc": [float(a) for a in warm.final_acc],
             "fair_acc": float(warm.fair_acc[-1][1]),
             "round_bytes": per_round,
@@ -168,8 +164,7 @@ def one_chip_phases():
 
     _, _, spec, cfg = common.scaled(quick=False)
     ds = make_clustered_data(spec, (30, 2), ("rot0", "rot180"))
-    yield run_phase("a", "facade", cfg, ds, rounds=80, eval_every=40,
-                    pipelined_second=True)
+    yield run_phase("a", "facade", cfg, ds, rounds=80, eval_every=40)
     yield run_phase("b", "el", cfg, ds, rounds=80, eval_every=40)
     cfg8 = resnet8()
     spec8 = SynthSpec(n_classes=cfg8.n_classes, image_size=cfg8.image_size,

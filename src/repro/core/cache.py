@@ -50,7 +50,7 @@ do (``tests/test_obs.py`` pins both directions).
 Donation caveat: segment programs donate their input :class:`EngineCarry`
 buffers. Reusing a cached engine across runs is safe precisely because
 each run builds a FRESH carry from its own seed; never feed a consumed
-carry back into ``run_segment``. Only the carry is donated: the segment
+carry back into ``dispatch_segment``. Only the carry is donated: the segment
 programs, the legacy per-round loop and checkpoint resume only READ the
 train arrays, which is why the kept train split can be reused as is.
 

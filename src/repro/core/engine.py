@@ -268,11 +268,10 @@ class SegmentEngine:
 
         Returns ``(new_carry, outs)`` where both are DEVICE values (the
         stacked per-round outs still live on device); pair with
-        :meth:`drain` to pull ``outs`` to the host. This is the pipelined
-        driver's half-step: it dispatches segment ``t+1`` off the fresh
-        carry before draining segment ``t``'s scalars, so host-side
-        bookkeeping overlaps device compute. The input ``carry`` is
-        donated — consumed either way.
+        :meth:`drain` to pull ``outs`` to the host. The engine loop
+        dispatches segment ``t+1`` off the fresh carry before draining
+        segment ``t``'s scalars, so host-side bookkeeping overlaps device
+        compute. The input ``carry`` is donated — consumed either way.
 
         The call is a ``compile`` span (first trace of this program in
         this process; it states ``compile_stats`` and ``nodes``) or a
@@ -298,25 +297,10 @@ class SegmentEngine:
 
     def drain(self, outs, tracer=None, length: int | None = None):
         """Pull a dispatched segment's stacked outs to the host (the
-        segment's only device->host transfer). In the serialized driver
-        the ``drain`` span absorbs device compute + transfer; in the
-        pipelined driver the next segment is already running, so the span
-        shrinks to the residual wait."""
+        segment's only device->host transfer). The engine loop drains
+        segment ``t`` once ``t+1`` is dispatched, so the ``drain`` span is
+        the residual wait for ``t``'s device compute + transfer, not the
+        segment's device time."""
         with span(tracer, "drain",
                   **({} if length is None else {"length": length})):
             return jax.device_get(outs)
-
-    def run_segment(self, carry: EngineCarry, start: int, length: int,
-                    train_x, train_y, warmup: bool = False, tracer=None):
-        """Advance ``length`` rounds in one dispatch and drain the outs.
-
-        Returns ``(new_carry, outs)`` where ``outs`` is a dict of host
-        numpy arrays with leading axis ``length``. Dispatch is async, so
-        the drain span absorbs device compute + transfer — the
-        serialization the ``pipeline=True`` driver overlaps away via
-        :meth:`dispatch_segment` + :meth:`drain`.
-        """
-        carry, outs = self.dispatch_segment(carry, start, length, train_x,
-                                            train_y, warmup=warmup,
-                                            tracer=tracer)
-        return carry, self.drain(outs, tracer=tracer, length=length)

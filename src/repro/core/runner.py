@@ -6,9 +6,10 @@ Two interchangeable drivers share all setup and evaluation code:
 
 * ``engine=True`` (default): the scan-fused segment engine
   (:mod:`repro.core.engine`) — one XLA dispatch and one device->host
-  transfer per eval-to-eval span, donated state buffers;
+  transfer per eval-to-eval span, donated state buffers, and segment
+  ``t+1`` dispatched before segment ``t`` is drained;
 * ``engine=False``: the legacy per-round Python loop, kept as the parity
-  reference and the baseline for ``benchmarks/round_throughput.py``.
+  reference.
 
 Both produce bit-identical trajectories for the same seed.
 
@@ -194,8 +195,8 @@ def make_evaluator(binding: Binding, node_cluster, test_x, test_y,
     ``evaluate.begin(models)`` / ``evaluate.finish(pending)`` split the
     call at the dispatch boundary: ``begin`` enqueues every per-cluster
     prediction asynchronously (no host sync), ``finish`` drains and
-    reduces. The pipelined engine driver uses the split to overlap eval
-    compute/drain with the next segment's device compute;
+    reduces. The engine loop uses the split to overlap the eval's host
+    reduction with the next segment's device compute;
     ``evaluate(models)`` == ``finish(begin(models))``. ``begin`` is
     ``evaluate.predict`` over ``evaluate.inputs(models)``, the per-cluster
     (gathered models, eval batches) pairs — exposed so their device
@@ -292,9 +293,9 @@ class _History:
 
     def eval_begin(self, state):
         """Enqueue the eval's per-cluster predictions asynchronously (no
-        host sync) — the pipelined driver calls this BEFORE dispatching
-        the next segment (which donates the state buffers), then settles
-        with :meth:`eval_finish` while that segment computes.
+        host sync) — the engine loop calls this BEFORE dispatching the
+        next segment (which donates the state buffers), then settles with
+        :meth:`eval_finish` while that segment computes.
 
         Alongside the prediction dispatches, an async device COPY of the
         state's cluster assignment is enqueued (FACADE only) for the
@@ -366,7 +367,6 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
                    net: "netsim.NetworkConfig | None" = None,
                    topo: "topo_mod.TopoConfig | None" = None,
                    engine: bool = True,
-                   pipeline: bool = False,
                    mesh=None,
                    cache: EngineCache | None = None,
                    eval_batch: int = 256,
@@ -404,15 +404,6 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
     with a tolerance. The mesh shape is part of the cache key, so
     sharded and unsharded programs never collide in an ``EngineCache``.
 
-    ``pipeline`` (engine driver only): double-buffer the segment loop —
-    segment ``t+1`` is dispatched (and ``t``'s eval enqueued) BEFORE
-    segment ``t``'s stacked scalars are drained, so host-side bookkeeping
-    (``device_get``, ``CommLog.record_bulk``, eval reduction, checkpoint
-    writes) overlaps device compute of ``t+1``. Bit-for-bit identical to
-    ``pipeline=False``: ``t+1`` consumes exactly the fresh carry ``t``
-    produced and the host processes segments in order; a ``target_acc``
-    hit discards at most one speculatively dispatched segment.
-
     ``cache``: optional :class:`repro.core.cache.EngineCache` shared across
     calls — a sweep of seeds over one config then pays the XLA compiles
     once (see :mod:`repro.sweep`), and every call after the first over the
@@ -440,16 +431,14 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
     crosses them (data PRNG, netsim channel, async gossip, topo EWMAs,
     crash chain) lives in the carry. A checkpoint written by a DIFFERENT
     run configuration is refused (fingerprint mismatch), never silently
-    reused.
+    reused. The cost: a checkpointed run holds one extra device copy of
+    the carry between dispatches, because segment ``t+1`` is dispatched
+    (donating the carry) before segment ``t``'s checkpoint is written.
     """
     if ckpt is not None and not engine:
         raise ValueError(
             "ckpt= needs the segment engine (engine=True): the legacy "
             "per-round loop has no segment boundaries to snapshot at")
-    if pipeline and not engine:
-        raise ValueError(
-            "pipeline=True needs the segment engine (engine=True): the "
-            "legacy per-round loop has no segment dispatch to overlap")
     mesh = meshctx.normalize(mesh)
     if mesh is not None and not engine:
         raise ValueError(
@@ -536,7 +525,7 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
             _drive_engine(entry.engine, setup, hist, k_data, train_x,
                           train_y, rounds=rounds, eval_every=eval_every,
                           warmup_rounds=warmup_rounds, obs=obs,
-                          ckpt=ckpt, ckpt_fp=ckpt_fp, pipeline=pipeline)
+                          ckpt=ckpt, ckpt_fp=ckpt_fp)
         else:
             _drive_legacy(setup, hist, k_data, train_x, train_y,
                           rounds=rounds, eval_every=eval_every,
@@ -558,8 +547,7 @@ def run_experiment(algo: str, cfg, dataset, *, rounds: int, k: int | None = None
         obs.end_run(obs_mod.RunManifest.build(
             kind="run", name=f"{algo}-seed{seed}", spec=spec,
             settings={"rounds": rounds, "eval_every": eval_every,
-                      "engine": engine, "pipeline": pipeline, "seed": seed,
-                      "net": repr(net),
+                      "engine": engine, "seed": seed, "net": repr(net),
                       "topo": repr(topo), "obs": repr(obs.config),
                       "jsonl": (None if sink_path is None
                                 else str(sink_path))},
@@ -729,25 +717,41 @@ def _ckpt_resume(ckpt, ckpt_fp, carry, hist, obs, tracer):
 
 def _drive_engine(eng, setup: AlgoSetup, hist: _History, k_data,
                   train_x, train_y, *, rounds, eval_every, warmup_rounds,
-                  obs=None, ckpt=None, ckpt_fp=None, pipeline=False):
-    """Segment-engine driver: one dispatch + one host transfer per span.
-    ``eng`` comes from the run's :class:`EngineCache` entry, so repeated
-    runs of one config reuse its compiled segment programs. ``obs``: the
-    run's :class:`repro.obs.Obs` — its tracer instruments every segment
-    (compile/dispatch/drain spans) and eval, and the segment's stacked
-    ``MetricsFrame`` (already drained in the one bulk ``device_get``) is
-    handed over whole — on a ``target_acc`` hit the full segment is
-    recorded (frames are pure observation; the early exit only truncates
-    the comm/cluster histories, matching the legacy loop's break).
+                  obs=None, ckpt=None, ckpt_fp=None):
+    """The segment-engine loop: one dispatch + one host transfer per span,
+    and while the host drains and processes segment ``t``, the device
+    already computes segment ``t+1``. ``eng`` comes from the run's
+    :class:`EngineCache` entry, so repeated runs of one config reuse its
+    compiled segment programs.
+
+    Order per segment ``t`` — the ordering is what makes donation safe:
+
+    1. enqueue ``t``'s finalize (last segment) and eval (async
+       ``predict`` dispatches reading ``carry.state``) and, under
+       ``ckpt``, an async device COPY of the carry — all capture the
+       buffers BEFORE they are donated;
+    2. dispatch ``t+1`` off the fresh carry (donates it);
+    3. drain ``t``'s stacked scalars and do its host bookkeeping
+       (``record_bulk``, eval reduction, cluster history, checkpoint
+       write), overlapping ``t+1``'s device compute.
+
+    Segments are processed on the host strictly in order with the values
+    the legacy loop computes, so the trajectory is its bit for bit. A
+    ``target_acc`` hit abandons the one dispatched segment after it (its
+    carry was consumed, its outs are never drained). ``obs``: the run's
+    :class:`repro.obs.Obs` — its tracer instruments every segment
+    (compile/dispatch/drain spans) and eval (both halves under ``eval``),
+    and the segment's stacked ``MetricsFrame`` (drained in the one bulk
+    ``device_get``) is handed over whole — on a ``target_acc`` hit the
+    full segment is recorded (frames are pure observation; the early exit
+    only truncates the comm/cluster histories, matching the legacy loop's
+    break).
 
     ``ckpt``/``ckpt_fp``: crash-safe resume. After every segment the carry
     + histories + frames are checkpointed (atomically); on entry, an
     existing checkpoint with a matching fingerprint fast-forwards the run
     to its ``next_segment``. Segments are deterministic functions of the
     carry, so the resumed trajectory is bit-for-bit the uninterrupted one.
-
-    ``pipeline``: double-buffered variant — see :func:`_drive_pipelined`.
-    ``False`` keeps this serialized loop bit-for-bit.
     """
     tracer = obs.tracer if obs is not None else None
     plan = segment_plan(rounds, eval_every, warmup_rounds)
@@ -763,40 +767,61 @@ def _drive_engine(eng, setup: AlgoSetup, hist: _History, k_data,
         carry = eng.place_carry(carry)
         if finished:
             return
-    if pipeline:
-        _drive_pipelined(eng, setup, hist, carry, plan, start_idx,
-                         n_frames, train_x, train_y, rounds=rounds,
-                         obs=obs, ckpt=ckpt, ckpt_fp=ckpt_fp)
-        return
+    if start_idx == len(plan):
+        return                              # rounds=0: nothing to run
+
+    def dispatch(i, c):
+        s = plan[i]
+        return eng.dispatch_segment(c, s.start, s.length, train_x,
+                                    train_y, warmup=s.warmup,
+                                    tracer=tracer)
+
+    carry, pending = dispatch(start_idx, carry)
     for idx in range(start_idx, len(plan)):
         seg = plan[idx]
-        carry, outs = eng.run_segment(carry, seg.start, seg.length,
-                                      train_x, train_y, warmup=seg.warmup,
-                                      tracer=tracer)
-        rnds = np.arange(seg.start + 1, seg.start + seg.length + 1)
+        last = idx + 1 == len(plan)
+        rnd = seg.start + seg.length
+        ev = None
+        if seg.eval_at_end:
+            state = carry.state
+            if rnd == rounds:
+                with span(tracer, "finalize"):
+                    state = setup.finalize(state)
+                carry = carry._replace(state=state)
+            with span(tracer, "eval", round=rnd):
+                ev = hist.eval_begin(state)
+        saved, nxt = carry, None
+        if not last:
+            if ckpt is not None:
+                saved = _snapshot(carry)
+            carry, nxt = dispatch(idx + 1, carry)
+        outs = eng.drain(pending, tracer=tracer, length=seg.length)
+        pending = nxt
+        rnds = np.arange(seg.start + 1, rnd + 1)
         if obs is not None and "frame" in outs:
             obs.record_frames(rnds, outs["frame"])
         _record_comm(hist, rnds, outs, seg.eval_at_end, tracer)
         hit = False
         if seg.eval_at_end:
-            state = carry.state
-            if seg.start + seg.length == rounds:
-                with span(tracer, "finalize"):
-                    state = setup.finalize(state)
-                carry = carry._replace(state=state)
-            with span(tracer, "eval", round=int(rnds[-1])):
-                hit = hist.eval_round(state, int(rnds[-1]),
-                                      float(outs["round_bytes"][-1]),
-                                      float(outs["round_s"][-1]))
+            with span(tracer, "eval", round=rnd):
+                hit = hist.eval_finish(ev, rnd,
+                                       float(outs["round_bytes"][-1]),
+                                       float(outs["round_s"][-1]))
         _record_clusters(hist, setup, rnds, outs, hit, tracer)
         if ckpt is not None:
             new_fr = (rnds, outs["frame"]) if "frame" in outs else None
-            finished = hit or idx + 1 == len(plan)
+            finished = hit or last
             with span(tracer, "ckpt.save", segment=idx, finished=finished):
-                n_frames = _ckpt_save(ckpt, ckpt_fp, carry, hist, new_fr,
+                n_frames = _ckpt_save(ckpt, ckpt_fp, saved, hist, new_fr,
                                       n_frames, idx + 1, finished)
         if hit:
             break
+
+
+def _snapshot(carry: EngineCarry) -> EngineCarry:
+    """An async device copy of ``carry``: the checkpoint reads its values
+    after the next dispatch has donated the carry's buffers."""
+    return jax.tree.map(jnp.copy, carry)
 
 
 def _record_comm(hist: _History, rnds, outs, eval_at_end: bool, tracer):
@@ -819,82 +844,6 @@ def _record_clusters(hist: _History, setup: AlgoSetup, rnds, outs,
         for i in range(len(rnds) - 1 if hit else len(rnds)):
             hist.cluster_hist.append(
                 (int(rnds[i]), np.asarray(outs["cluster_id"][i])))
-
-
-def _drive_pipelined(eng, setup: AlgoSetup, hist: _History, carry, plan,
-                     start_idx, n_frames, train_x, train_y, *, rounds,
-                     obs=None, ckpt=None, ckpt_fp=None):
-    """Double-buffered segment loop: while the host drains and processes
-    segment ``t``, the device already computes segment ``t+1``.
-
-    Order per iteration — the ordering is what makes donation safe:
-
-    1. enqueue segment ``t``'s eval (async ``predict`` dispatches reading
-       ``carry.state``) and, under ``ckpt``, an async device-side COPY of
-       the carry — both capture the buffers BEFORE they are donated;
-    2. dispatch segment ``t+1`` off the fresh carry (donates it);
-    3. drain segment ``t``'s stacked scalars and do all host bookkeeping
-       (``record_bulk``, eval reduction, cluster history, checkpoint
-       write) — now overlapping ``t+1``'s device compute.
-
-    Host-side processing happens strictly in segment order with the same
-    values as the serialized loop, so results are bit-for-bit identical.
-    A ``target_acc`` hit abandons the one speculatively dispatched
-    segment (its carry was consumed, its outs are never drained)."""
-    tracer = obs.tracer if obs is not None else None
-    if start_idx >= len(plan):
-        return
-
-    def dispatch(i, c):
-        s = plan[i]
-        return eng.dispatch_segment(c, s.start, s.length, train_x,
-                                    train_y, warmup=s.warmup,
-                                    tracer=tracer)
-
-    next_carry, pending = dispatch(start_idx, carry)
-    for idx in range(start_idx, len(plan)):
-        seg = plan[idx]
-        carry = next_carry
-        ev = None
-        if seg.eval_at_end:
-            state = carry.state
-            if seg.start + seg.length == rounds:
-                with span(tracer, "finalize"):
-                    state = setup.finalize(state)
-                carry = carry._replace(state=state)
-            with span(tracer, "eval", round=seg.start + seg.length):
-                ev = hist.eval_begin(state)
-        snap = None
-        if idx + 1 < len(plan):
-            if ckpt is not None:
-                # async device copy: the checkpoint needs this carry's
-                # values AFTER the next dispatch has donated its buffers
-                snap = jax.tree.map(jnp.copy, carry)
-            next_carry, pending_next = dispatch(idx + 1, carry)
-        outs = eng.drain(pending, tracer=tracer, length=seg.length)
-        if idx + 1 < len(plan):
-            pending = pending_next
-        rnds = np.arange(seg.start + 1, seg.start + seg.length + 1)
-        if obs is not None and "frame" in outs:
-            obs.record_frames(rnds, outs["frame"])
-        _record_comm(hist, rnds, outs, seg.eval_at_end, tracer)
-        hit = False
-        if seg.eval_at_end:
-            with span(tracer, "eval", round=int(rnds[-1])):
-                hit = hist.eval_finish(ev, int(rnds[-1]),
-                                       float(outs["round_bytes"][-1]),
-                                       float(outs["round_s"][-1]))
-        _record_clusters(hist, setup, rnds, outs, hit, tracer)
-        if ckpt is not None:
-            new_fr = (rnds, outs["frame"]) if "frame" in outs else None
-            finished = hit or idx + 1 == len(plan)
-            with span(tracer, "ckpt.save", segment=idx, finished=finished):
-                n_frames = _ckpt_save(ckpt, ckpt_fp,
-                                      snap if snap is not None else carry,
-                                      hist, new_fr, n_frames, idx + 1,
-                                      finished)
-        if hit:
-            break
 
 
 def _drive_legacy(setup: AlgoSetup, hist: _History, k_data, train_x, train_y,

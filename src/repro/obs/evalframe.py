@@ -18,7 +18,7 @@ the ``EngineSpec`` cache key and is recorded whether or not a device
 
 :func:`compute_eval_frame` is the ONE shared recording hook both
 drivers call (inside ``_History.eval_finish``, the single eval
-bottleneck the engine, legacy and pipelined loops all route through —
+bottleneck the engine and legacy loops both route through —
 the ``compute_frame`` discipline from the PR 6 contract), which is what
 keeps the series engine/legacy bit-identical AND keeps the series'
 final entry bit-for-bit equal to ``RunResult.dp``/``RunResult.eo``:

@@ -1,10 +1,9 @@
 """Program spans for the training and serving drivers, on the host and on
 the profiler's clock.
 
-Phase-level wall-clock is the instrument every later perf PR (sharding,
-pipelined segments — ROADMAP Open Items 1 and 5) needs: you cannot
-overlap segment dispatch with scalar drain until you can SEE how long
-each takes. :class:`Tracer` provides nested spans (``compile`` /
+Phase-level wall-clock is the instrument every perf change needs: you
+cannot tell where a round's time goes until you can SEE how long each
+phase takes. :class:`Tracer` provides nested spans (``compile`` /
 ``dispatch`` / ``drain`` / ``eval`` in the engine; ``prefill`` /
 ``decode`` in serving) with microsecond timestamps, point events
 (``EngineCache`` hits/misses, SLO summaries), an aggregate
@@ -46,12 +45,12 @@ Timing semantics at the dispatch boundary: JAX dispatch is
 asynchronous, so a ``dispatch`` span measures trace+enqueue time while
 the following ``drain`` span (which blocks on ``device_get``) absorbs
 device compute + transfer. A ``compile`` span wraps the first call of a
-segment program, where XLA compilation dominates. Under
-``run_experiment(pipeline=True)`` segment ``t+1`` is dispatched before
-``t`` is drained, so the device is already working while the host
-blocks: ``drain`` shrinks to the RESIDUAL wait (often ~0 once the
-pipeline is full) and the sum of ``drain`` spans no longer approximates
-device time — compare wall-clock across the ``run`` span instead. A
+segment program, where XLA compilation dominates. The engine loop
+dispatches segment ``t+1`` before ``t`` is drained, so the device is
+already working while the host blocks: ``drain`` is the RESIDUAL wait
+(often ~0 when host work covers the segment) and the sum of ``drain``
+spans does not approximate device time — compare wall-clock across the
+``run`` span instead, or read device time off a profile. A
 ``compile`` span can also be near-instant when the executable was
 deserialized from the persistent compile cache
 (:func:`repro.core.cache.use_compile_cache`): the span still marks the first

@@ -5,11 +5,11 @@ device, keyed on the dataset object (held weakly), the identity of its
 ``train_x`` / ``train_y`` arrays and the node-mesh shape. A second run
 over the same dataset moves 0 bytes (the ``upload`` span's ``bytes``,
 ``data_hits`` / ``data_uploads``, the ``data.hit`` / ``data.miss`` events)
-and gives the fresh-cache run bit for bit under every driver; a
-reassigned array, another dataset or another placement uploads again,
-releasing the old arrays first; deleting the dataset frees them; and a
-``ClusteredDataset`` is hashed by identity, so ``data_fingerprint``
-hashes its content once.
+and gives the fresh-cache run bit for bit under both drivers, the engine
+checkpointed or not; a reassigned array, another dataset or another
+placement uploads again, releasing the old arrays first; deleting the
+dataset frees them; and a ``ClusteredDataset`` is hashed by identity, so
+``data_fingerprint`` hashes its content once.
 """
 import dataclasses
 import gc
@@ -31,9 +31,7 @@ pytestmark = pytest.mark.tier0
 CFG = lenet(smoke=True).replace(n_classes=4)
 KW = dict(rounds=2, k=2, degree=2, local_steps=2, batch_size=4, lr=0.05,
           eval_every=1)
-DRIVERS = {"serialized": dict(engine=True, pipeline=False),
-           "pipelined": dict(engine=True, pipeline=True),
-           "legacy": dict(engine=False)}
+DRIVERS = ("engine", "checkpointed", "legacy")
 # 7 samples a class: a train shape [n, 28, 16, 16, 3] no other test makes,
 # so jax.live_arrays() finds only this file's train arrays by shape
 PER_CLASS = 7
@@ -66,21 +64,31 @@ def _assert_runs_identical(ref, got):
         np.testing.assert_array_equal(c1, c2)
 
 
+def _driver(driver: str, tmp_path, run: str) -> dict:
+    """``run_experiment`` arguments for ``driver``; a checkpointed run
+    writes its own archive, named by ``run``."""
+    if driver == "legacy":
+        return {"engine": False}
+    if driver == "checkpointed":
+        return {"ckpt": str(tmp_path / f"{run}.npz")}
+    return {}
+
+
 def _upload(obs) -> dict:
     return obs.tracer.rollup()["spans"]["upload"]
 
 
 # ------------------------------------------------------------- hits --
-@pytest.mark.parametrize("driver", list(DRIVERS))
-def test_a_second_run_over_one_dataset_moves_no_bytes(driver):
+@pytest.mark.parametrize("driver", DRIVERS)
+def test_a_second_run_over_one_dataset_moves_no_bytes(driver, tmp_path):
     ds, cache = _dataset(), EngineCache()
     first, second = Obs(None, health=None), Obs(None, health=None)
     run_experiment("facade", CFG, ds, cache=cache, obs=first, seed=0,
-                   **KW, **DRIVERS[driver])
+                   **KW, **_driver(driver, tmp_path, "first"))
     assert _upload(first)["bytes"] == ds.train_x.nbytes + ds.train_y.nbytes
     assert first.tracer.rollup()["events"]["data.miss"] == 1
     run_experiment("facade", CFG, ds, cache=cache, obs=second, seed=1,
-                   **KW, **DRIVERS[driver])
+                   **KW, **_driver(driver, tmp_path, "second"))
     assert _upload(second)["count"] == 1 and _upload(second)["bytes"] == 0
     events = second.tracer.rollup()["events"]
     assert events["data.hit"] == 1 and "data.miss" not in events
@@ -89,18 +97,19 @@ def test_a_second_run_over_one_dataset_moves_no_bytes(driver):
     assert second.manifests[-1].cache["data_hits"] == 1
 
 
-@pytest.mark.parametrize("driver", list(DRIVERS))
-def test_a_kept_train_split_gives_the_fresh_run_bit_for_bit(driver):
+@pytest.mark.parametrize("driver", DRIVERS)
+def test_a_kept_train_split_gives_the_fresh_run_bit_for_bit(driver,
+                                                            tmp_path):
     """Only the carry is donated: a run over the kept train arrays (after
     another run read them) is the fresh-cache run of its seed exactly."""
     ds, cache = _dataset(), EngineCache()
     run_experiment("facade", CFG, ds, cache=cache, seed=5, **KW,
-                   **DRIVERS[driver])
+                   **_driver(driver, tmp_path, "other"))
     got = run_experiment("facade", CFG, ds, cache=cache, seed=6, **KW,
-                         **DRIVERS[driver])
+                         **_driver(driver, tmp_path, "kept"))
     assert cache.data_hits == 1
     ref = run_experiment("facade", CFG, ds, cache=EngineCache(), seed=6,
-                         **KW, **DRIVERS[driver])
+                         **KW, **_driver(driver, tmp_path, "fresh"))
     _assert_runs_identical(ref, got)
 
 

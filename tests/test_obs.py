@@ -106,8 +106,10 @@ def test_obs_parity_under_netsim(tiny_ds):
 
 
 # ------------------------------------------- engine/legacy frame parity --
-@pytest.mark.parametrize("preset", [None, "async-edge", "edge-v2"])
-@pytest.mark.parametrize("algo", ["facade", "el"])
+@pytest.mark.parametrize("algo,preset", [
+    (algo, preset) for algo in ("facade", "el")
+    for preset in (None, "async-edge", "edge-v2")] + [
+    ("dpsgd", "edge-v2"), ("deprl", "edge-v2"), ("dac", "edge-v2")])
 def test_engine_and_legacy_frames_identical(algo, preset, tiny_ds):
     """Both drivers run the one shared ``compute_frame`` at the same
     point in the round — frames must agree like trajectories do."""

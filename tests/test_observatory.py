@@ -238,8 +238,10 @@ def test_resume_preserves_eval_frames(tiny_ds, tmp_path):
     class _Killed(Exception):
         pass
 
+    # die draining the second segment: the first one's checkpoint has
+    # landed by then
     ck = str(tmp_path / "killed.npz")
-    orig = engine_mod.SegmentEngine.run_segment
+    orig = engine_mod.SegmentEngine.drain
     calls = {"n": 0}
 
     def killer(self, *a, **k):
@@ -248,13 +250,13 @@ def test_resume_preserves_eval_frames(tiny_ds, tmp_path):
         calls["n"] += 1
         return orig(self, *a, **k)
 
-    engine_mod.SegmentEngine.run_segment = killer
+    engine_mod.SegmentEngine.drain = killer
     try:
         with pytest.raises(_Killed):
             run_experiment("facade", CFG, tiny_ds, obs=Obs(ObsConfig()),
                            ckpt=ck, **KW)
     finally:
-        engine_mod.SegmentEngine.run_segment = orig
+        engine_mod.SegmentEngine.drain = orig
 
     obs = Obs(ObsConfig())
     got = run_experiment("facade", CFG, tiny_ds, obs=obs, ckpt=ck, **KW)

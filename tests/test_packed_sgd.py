@@ -177,8 +177,9 @@ def test_compile_span_states_the_sgd_path(monkeypatch):
     data = _batches(LENET, 4, 1, 8)
     tracer = Tracer()
     for _ in range(2):
-        carry, _ = entry.engine.run_segment(
+        carry, outs = entry.engine.dispatch_segment(
             carry, 0, 1, data["x"][:, 0], data["y"][:, 0], tracer=tracer)
+        entry.engine.drain(outs, tracer=tracer)
     spans = tracer.rollup()["spans"]
     assert spans["compile"]["sgd_path"] == {"packed": 1}
     assert "sgd_path" not in spans["dispatch"]

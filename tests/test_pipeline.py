@@ -1,12 +1,10 @@
-"""Always-warm engine (ROADMAP Open Item 5a): the pipelined segment
-driver, the persistent/LRU-bounded ``EngineCache``, and the validation
-fixes that rode along.
+"""Always-warm engine (ROADMAP Open Item 5a): the persistent/LRU-bounded
+``EngineCache``, and the validation fixes that rode along.
 
-* ``run_experiment(pipeline=True)`` double-buffers the segment loop —
-  dispatch ``t+1`` before draining ``t`` — and must stay bit-for-bit
-  identical to the serialized driver for every algorithm: metrics,
-  CommLog, obs frames, cluster history and the FINAL CARRY.
-* kill + resume under ``pipeline=True`` lands on the same trajectory.
+* kill + resume while a segment is in flight lands on the uninterrupted
+  trajectory: the engine loop dispatches segment ``t+1`` before it
+  drains ``t``, and its checkpoint snapshots the carry before that
+  dispatch donates it.
 * ``EngineCache(persist_dir=...)`` persists XLA executables on disk
   without perturbing results; ``max_entries`` LRU-evicts, but never an
   entry pinned by a live run.
@@ -19,7 +17,6 @@ fixes that rode along.
 import pathlib
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -37,7 +34,6 @@ from repro.obs import Obs, ObsConfig
 pytestmark = pytest.mark.tier0
 
 CFG = lenet(smoke=True).replace(n_classes=4)
-ALL_ALGOS = ("facade", "el", "dpsgd", "deprl", "dac")
 KW = dict(rounds=6, k=2, degree=2, local_steps=2, batch_size=4, lr=0.05,
           eval_every=2, seed=0)
 
@@ -75,79 +71,23 @@ def _assert_frames_identical(obs_a: Obs, obs_b: Obs):
                                       np.asarray(fb[k]))
 
 
-# --------------------------------------------------- pipeline parity ------
-@pytest.mark.parametrize("algo", ALL_ALGOS)
-def test_pipeline_matches_serialized_bitforbit(algo, tiny_ds):
-    """The headline contract: pipeline=True is a pure scheduling change.
-    edge-v2 carries channel state + async gossip through the overlap and
-    obs frames ride in the same drained outs — everything must agree down
-    to the last bit, including the frame stream."""
-    net = NetworkConfig.preset("edge-v2")
-    cache = EngineCache()
-    ocfg = ObsConfig()
-    obs_ref, obs_got = Obs(config=ocfg), Obs(config=ocfg)
-    ref = run_experiment(algo, CFG, tiny_ds, net=net, cache=cache,
-                         obs=obs_ref, pipeline=False, **KW)
-    got = run_experiment(algo, CFG, tiny_ds, net=net, cache=cache,
-                         obs=obs_got, pipeline=True, **KW)
-    _assert_runs_identical(ref, got)
-    _assert_frames_identical(obs_ref, obs_got)
-
-
-def test_pipeline_final_carry_parity(tiny_ds, tmp_path):
-    """The checkpointed final carry (params, PRNG, netsim channel) is
-    leaf-for-leaf identical across the serialized and pipelined drivers —
-    the pipelined checkpoint snapshots the carry BEFORE the speculative
-    next dispatch donates it."""
-    net = NetworkConfig.preset("edge-v2")
-    cache = EngineCache()
-    ck_ref = str(tmp_path / "serial.npz")
-    ck_got = str(tmp_path / "pipe.npz")
-    ref = run_experiment("facade", CFG, tiny_ds, net=net, cache=cache,
-                         ckpt=ck_ref, pipeline=False, **KW)
-    got = run_experiment("facade", CFG, tiny_ds, net=net, cache=cache,
-                         ckpt=ck_got, pipeline=True, **KW)
-    _assert_runs_identical(ref, got)
-    pr, _ = checkpoint.load(ck_ref)
-    pg, _ = checkpoint.load(ck_got)
-    for a, b in zip(jax.tree.leaves(pr["carry"]),
-                    jax.tree.leaves(pg["carry"])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_pipeline_target_acc_stops_at_same_round(tiny_ds):
-    """target_acc discards at most the one speculatively dispatched
-    segment: the recorded trajectory still stops at the same eval round
-    as the serialized driver."""
-    kw = {**KW, "rounds": 8, "target_acc": 0.0}
-    ref = run_experiment("el", CFG, tiny_ds, pipeline=False, **kw)
-    got = run_experiment("el", CFG, tiny_ds, pipeline=True, **kw)
-    _assert_runs_identical(ref, got)
-    assert got.comm.rounds[-1] == 2          # stopped at the first eval
-
-
-def test_pipeline_requires_engine(tiny_ds):
-    with pytest.raises(ValueError, match="engine"):
-        run_experiment("el", CFG, tiny_ds, engine=False, pipeline=True,
-                       **KW)
-
-
-# ------------------------------------------------ pipelined kill+resume ---
+# ------------------------------------------------ in-flight kill+resume ---
 class _Killed(Exception):
     pass
 
 
 def test_pipeline_kill_and_resume_bit_parity(tiny_ds, tmp_path):
-    """Kill the pipelined driver mid-flight (on the speculative dispatch
-    of segment 2, after segment 0's checkpoint landed) and resume with
-    the same pipelined call: indistinguishable from an uninterrupted
-    serialized run — metrics, frames, and the final checkpointed carry."""
+    """Kill the engine loop mid-flight (on the dispatch of segment 2,
+    after segment 0's checkpoint landed) and resume with the same call:
+    indistinguishable from an uninterrupted run — metrics, frames, and
+    the final checkpointed carry, which the snapshot before each dispatch
+    keeps whole."""
     net = NetworkConfig.preset("edge-churn")
     ocfg = ObsConfig()
     obs_ref = Obs(config=ocfg)
     ck_ref = str(tmp_path / "ref.npz")
     ref = run_experiment("facade", CFG, tiny_ds, net=net, ckpt=ck_ref,
-                         obs=obs_ref, pipeline=False, **KW)
+                         obs=obs_ref, **KW)
 
     orig = engine_mod.SegmentEngine.dispatch_segment
     calls = {"n": 0}
@@ -164,14 +104,14 @@ def test_pipeline_kill_and_resume_bit_parity(tiny_ds, tmp_path):
     try:
         with pytest.raises(_Killed):
             run_experiment("facade", CFG, tiny_ds, net=net, ckpt=ck,
-                           obs=obs_dead, pipeline=True, **KW)
+                           obs=obs_dead, **KW)
     finally:
         engine_mod.SegmentEngine.dispatch_segment = orig
     assert pathlib.Path(ck).exists()     # segment 0 landed before the kill
 
     obs_got = Obs(config=ocfg)
     got = run_experiment("facade", CFG, tiny_ds, net=net, ckpt=ck,
-                         obs=obs_got, pipeline=True, **KW)
+                         obs=obs_got, **KW)
     _assert_runs_identical(ref, got)
     _assert_frames_identical(obs_ref, obs_got)
     pr, _ = checkpoint.load(ck_ref)
@@ -179,17 +119,6 @@ def test_pipeline_kill_and_resume_bit_parity(tiny_ds, tmp_path):
     for a, b in zip(jax.tree.leaves(pr["carry"]),
                     jax.tree.leaves(pg["carry"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_pipeline_resume_across_driver_variants(tiny_ds, tmp_path):
-    """The ckpt fingerprint deliberately excludes ``pipeline`` (identical
-    trajectory => identical resume schedule): a checkpoint written by the
-    serialized driver resumes under the pipelined one."""
-    ck = str(tmp_path / "cross.npz")
-    ref = run_experiment("el", CFG, tiny_ds, ckpt=ck, pipeline=False, **KW)
-    again = run_experiment("el", CFG, tiny_ds, ckpt=ck, pipeline=True,
-                           **KW)                  # finished: no-op replay
-    _assert_runs_identical(ref, again)
 
 
 # ----------------------------------------------------- persist_dir --------

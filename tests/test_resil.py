@@ -341,9 +341,10 @@ class _Killed(Exception):
 
 
 def _run_killed_then_resume(algo, ds, net, ck, kw, obs_cfg=None):
-    """Run with ckpt, kill after the first segment, then resume. Returns
-    the resumed result and its Obs."""
-    orig = engine_mod.SegmentEngine.run_segment
+    """Run with ckpt, kill while draining the second segment (the first
+    one's checkpoint has landed), then resume. Returns the resumed result
+    and its Obs."""
+    orig = engine_mod.SegmentEngine.drain
     calls = {"n": 0}
 
     def killer(self, *a, **k):
@@ -353,12 +354,13 @@ def _run_killed_then_resume(algo, ds, net, ck, kw, obs_cfg=None):
         return orig(self, *a, **k)
 
     obs = Obs(config=obs_cfg) if obs_cfg is not None else None
-    engine_mod.SegmentEngine.run_segment = killer
+    engine_mod.SegmentEngine.drain = killer
     try:
         with pytest.raises(_Killed):
             run_experiment(algo, CFG, ds, net=net, ckpt=ck, obs=obs, **kw)
     finally:
-        engine_mod.SegmentEngine.run_segment = orig
+        engine_mod.SegmentEngine.drain = orig
+    assert checkpoint.load(ck)[1]["next_segment"] == 1
     obs2 = Obs(config=obs_cfg) if obs_cfg is not None else None
     got = run_experiment(algo, CFG, ds, net=net, ckpt=ck, obs=obs2, **kw)
     return got, obs2

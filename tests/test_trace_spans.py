@@ -133,16 +133,19 @@ def test_compile_stats_name_the_model_and_its_pack_groups(cfg, n, want):
 
 
 # ------------------------------------------------- the drivers' spans --
-@pytest.mark.parametrize("driver", ["engine", "pipelined", "legacy"])
-def test_every_driver_opens_the_program_spans(driver, tiny_ds, annotations):
+@pytest.mark.parametrize("driver", ["engine", "checkpointed", "legacy"])
+def test_every_driver_opens_the_program_spans(driver, tiny_ds, annotations,
+                                              tmp_path):
     obs = Obs(None, health=None)
+    ckpt = str(tmp_path / "run.npz") if driver == "checkpointed" else None
     run_experiment("facade", CFG, tiny_ds, obs=obs,
-                   engine=driver != "legacy", pipeline=driver == "pipelined",
-                   **KW)
+                   engine=driver != "legacy", ckpt=ckpt, **KW)
     roll = obs.tracer.rollup()["spans"]
     want = {"run", "upload", "setup", "finalize", "eval", "cache.entry"}
     if driver != "legacy":
         want |= {"record", "compile", "drain"}
+    if ckpt is not None:
+        want.add("ckpt.save")
     assert want <= set(roll), sorted(roll)
     assert roll["finalize"]["count"] == 1
     # the profiler sees the same spans, under repro.<name>
